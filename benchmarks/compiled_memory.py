@@ -28,7 +28,8 @@ def _path(tag: str) -> str:
 def _ensure(chunks: int, tag: str) -> dict:
     p = _path(tag)
     if not os.path.exists(p):
-        env = {**os.environ, "PYTHONPATH": "src"}
+        # the dry-run compiles on virtual CPU devices, never the accelerator
+        env = {**os.environ, "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu"}
         subprocess.run(
             [sys.executable, "-m", "repro.launch.dryrun", "--arch", ARCH,
              "--shape", SHAPE, "--chunks", str(chunks), "--tag", tag,

@@ -45,17 +45,17 @@ os.environ["XLA_FLAGS"] = (
     "--xla_cpu_enable_concurrency_optimized_scheduler=true")
 import json, statistics, time
 import jax, jax.numpy as jnp
-from repro.compat import set_mesh
 from repro.core import moe as M
 from repro.configs.base import MoEConfig
 
 cfg = MoEConfig(num_experts={EXPERTS}, top_k={TOP_K}, d_ff_expert={D_FF})
-mesh = jax.make_mesh((1, {DEVICES}), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((1, {DEVICES}), ("data", "model"))
 params = M.init_moe(jax.random.PRNGKey(0), {D}, cfg)
 x = jax.random.normal(jax.random.PRNGKey(1), ({B}, {S}, {D}))
 
 rows = []
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     for chunks in {CHUNKS}:
         depths = sorted({{2, chunks}})
         ctxs = {{"seq": M.DistContext(mesh=mesh, moe_chunks=chunks,
@@ -100,9 +100,12 @@ def run() -> list[str]:
     path = os.path.join(repo, "src")
     if os.environ.get("PYTHONPATH"):
         path = path + os.pathsep + os.environ["PYTHONPATH"]
+    # virtual CPU devices: the child never reaches for an accelerator the
+    # parent process may hold
     out = subprocess.run([sys.executable, "-c", _INNER], capture_output=True,
                          text=True, timeout=1800,
-                         env={**os.environ, "PYTHONPATH": path})
+                         env={**os.environ, "PYTHONPATH": path,
+                              "JAX_PLATFORMS": "cpu"})
     if out.returncode != 0:
         raise RuntimeError(f"pipeline microbench subprocess failed:\n"
                            f"{out.stdout}\n{out.stderr}")

@@ -55,14 +55,14 @@ os.environ["XLA_FLAGS"] = (
 import json, math, statistics, time
 import numpy as np
 import jax, jax.numpy as jnp
-from repro.compat import set_mesh
 from repro.core import moe as M
 from repro.core import placement as plc
 from repro.configs.base import MoEConfig
 
 E, K, B, S, D = {EXPERTS}, {TOP_K}, {B}, {S}, {D}
 cfg = MoEConfig(num_experts=E, top_k=K, d_ff_expert={D_FF})
-mesh = jax.make_mesh((1, {DEVICES}), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((1, {DEVICES}), ("data", "model"))
 params = M.init_moe(jax.random.PRNGKey(0), D, cfg)
 # router reads the first E features verbatim: a two-hot spike per token
 # forces its (top1, top2) pair exactly
@@ -88,7 +88,7 @@ def ctx_for(placement=None):
                          placement=placement)
 
 # -- part 1: real EP step on the mesh — parity + the observed load ----------
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     step = jax.jit(lambda p, x, c=ctx_for(): M.moe_ffn(p, x, cfg, c))
     y_skew, s_skew = step(params, x_skew)
     _, s_bal = step(params, x_bal)
@@ -96,7 +96,7 @@ load = np.asarray(s_skew["load"], np.float64)
 assert load[0] == B * S and load[1] == B * S, load   # the forcing worked
 spec = plc.plan_placement(load, {DEVICES}, replicas=1)
 ident = plc.PlacementSpec.identity(E, {DEVICES})
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     y_placed, s_placed = jax.jit(
         lambda p, x, c=ctx_for(spec): M.moe_ffn(p, x, cfg, c))(params, x_skew)
 np.testing.assert_array_equal(np.asarray(y_skew), np.asarray(y_placed))
@@ -155,9 +155,12 @@ def run() -> list[str]:
     path = os.path.join(repo, "src")
     if os.environ.get("PYTHONPATH"):
         path = path + os.pathsep + os.environ["PYTHONPATH"]
+    # virtual CPU devices: the child never reaches for an accelerator the
+    # parent process may hold
     out = subprocess.run([sys.executable, "-c", _INNER], capture_output=True,
                          text=True, timeout=1800,
-                         env={**os.environ, "PYTHONPATH": path})
+                         env={**os.environ, "PYTHONPATH": path,
+                              "JAX_PLATFORMS": "cpu"})
     if out.returncode != 0:
         raise RuntimeError(f"placement microbench subprocess failed:\n"
                            f"{out.stdout}\n{out.stderr}")

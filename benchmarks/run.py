@@ -38,20 +38,27 @@ SUITES = {
 }
 
 
-def main() -> None:
+def main() -> int:
+    """Run the named suites (default: all); returns 1 if any suite raised.
+    A failing suite is reported and the rest still run."""
     names = sys.argv[1:] or list(SUITES)
+    failed = []
     for name in names:
         fn = SUITES[name]
         t0 = time.perf_counter()
         try:
             lines = fn()
-        except Exception as e:  # noqa: BLE001 — benches report, don't crash
+        except Exception as e:  # noqa: BLE001 — report, run the rest, fail
             lines = [f"{name},ERROR,{type(e).__name__}: {e}"]
+            failed.append(name)
         dt = time.perf_counter() - t0
         for line in lines:
             print(line, flush=True)
         print(f"{name},elapsed_s={dt:.1f}", flush=True)
+    if failed:
+        print(f"FAILED suites: {','.join(failed)}", file=sys.stderr, flush=True)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -218,7 +218,22 @@ class HardwareProfile:
 TPU_V5E = HardwareProfile("tpu-v5e", 16e9, 197e12, 819e9, 50e9)
 GPU_64G = HardwareProfile("gpu-64g", 64e9, 197e12, 819e9, 50e9)   # paper's 64 GB devices
 
-PROFILES = {p.name: p for p in (TPU_V5E, GPU_64G)}
+#: The profile each ``jax.Device.device_kind`` plans against.  The CPU
+#: backend runs the tests and the rehearsals of chip runs, so it plans
+#: against the v5e it rehearses for.
+DEVICE_PROFILES = {"TPU v5 lite": TPU_V5E, "cpu": TPU_V5E}
+
+
+def device_profile(kind: Optional[str] = None) -> HardwareProfile:
+    """The hardware profile of ``kind`` (default: the first local device's
+    ``device_kind``).  A kind with no profile is an error, never a default."""
+    if kind is None:
+        import jax
+        kind = jax.devices()[0].device_kind
+    if kind not in DEVICE_PROFILES:
+        raise ValueError(f"no hardware profile for device kind {kind!r}; "
+                         f"known: {sorted(DEVICE_PROFILES)}")
+    return DEVICE_PROFILES[kind]
 
 
 # ---------------------------------------------------------------------------

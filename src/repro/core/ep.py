@@ -28,8 +28,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-from repro.compat import shard_map
 from repro.configs.base import MoEConfig
 from repro.core import dispatch as dsp
 from repro.core.chunking import ChunkStages, chunked_pipeline
@@ -50,7 +48,7 @@ def _ep_local(x_l, router_w, router_b, w1, w3, w2, *, moe_cfg: MoEConfig,
               ragged_block: int = RAGGED_BLOCK, fused: bool = False,
               placement: PlacementSpec | None = None):
     """Per-device body. x_l: (B_l, S_l, d) local tokens."""
-    peers = compat.axis_size(ep_axis)
+    peers = lax.axis_size(ep_axis)
     E = moe_cfg.num_experts
     # With a placement the dispatch groups are weight SLOTS, not expert ids:
     # the single-sort planner is group-id agnostic, so sorting by slot id
@@ -232,7 +230,7 @@ def moe_ffn_ep(params: dict, x: jax.Array, moe_cfg: MoEConfig, mesh, *,
         ragged_block=ragged_block, fused=fused, placement=placement)
     x_spec = P(tuple(batch_axes), ep_axis, None)
     stats_spec = {"aux_loss": P(), "load": P(None), "drops": P()}
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(x_spec, P(None, None), P(None),
                   P(ep_axis, None, None), P(ep_axis, None, None),

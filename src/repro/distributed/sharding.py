@@ -102,6 +102,21 @@ def logits_pspec(mesh: Mesh, batch: int, vocab: int) -> P:
     return P(ba, None, guarded(mesh, vocab, "model"))
 
 
+def context_shardings(mesh: Mesh, cfg: ModelConfig, batch: int,
+                      seq_axis=None) -> dict:
+    """The ``DistContext`` fields that place a step's activations on
+    ``mesh``: batch axes, (B, S, d) activations, (B, S, V) logits and
+    (B, S, H, hd) attention heads.  ``seq_axis`` additionally shards the
+    activations' sequence dim (sequence parallelism)."""
+    ba = guarded(mesh, batch, batch_axes(mesh))
+    return dict(
+        batch_axes=batch_axes(mesh),
+        act_pspec=NamedSharding(mesh, P(ba, seq_axis, None)),
+        logits_pspec=NamedSharding(mesh, logits_pspec(mesh, batch,
+                                                      cfg.padded_vocab)),
+        heads_pspec=NamedSharding(mesh, P(ba, None, "model", None)))
+
+
 def batch_pspec(mesh: Mesh, batch: int) -> P:
     ba = guarded(mesh, batch, batch_axes(mesh))
     return P(ba, None)
@@ -113,9 +128,6 @@ def cache_pspec(mesh: Mesh, leaf_shape: tuple, batch: int) -> P:
     largest (sequence) dim — the single-sequence long-context case."""
     ba = batch_axes(mesh)
     n = axis_size(mesh, ba)
-    # normalise singleton axis tuples to bare names (new jax does this inside
-    # PartitionSpec; old jax keeps the 1-tuple, breaking == comparisons)
-    ba = ba[0] if isinstance(ba, tuple) and len(ba) == 1 else ba
     dims: list = [None] * len(leaf_shape)
     if n <= 1 or not leaf_shape:
         return P(*dims)

@@ -15,11 +15,13 @@ owns *how* winners are found and remembered.
   (kernels/tiling.py::choose_block), the space is a free grid — not just
   divisors.
 * **Persistence** — winners are stored per ``(op, shape, dtype,
-  device_kind)`` in an on-disk JSON cache (``REPRO_AUTOTUNE_CACHE`` or
-  ``~/.cache/repro/autotune.json``).  Every kernel in the package consults
-  it through ``tiling.resolve_tiles`` at trace time; a missing or corrupt
-  cache silently falls back to the heuristic defaults — tuning is an
-  optimization, never a correctness dependency.
+  device_kind)`` in ``tiles.json`` next to this module, a file the
+  repository commits (``set_cache_path`` points a process elsewhere, as the
+  tests do).  What a kernel compiles to therefore depends only on the
+  checkout.  Every kernel in the package consults it through
+  ``tiling.resolve_tiles`` at trace time; a missing or corrupt file falls
+  back to the heuristic defaults — tuning is an optimization, never a
+  correctness dependency.
 """
 
 from __future__ import annotations
@@ -31,27 +33,26 @@ import time
 from dataclasses import dataclass, field
 from typing import Callable, Optional, Sequence
 
-DEFAULT_CACHE = os.path.join(os.path.expanduser("~"), ".cache", "repro",
-                             "autotune.json")
-_ENV = "REPRO_AUTOTUNE_CACHE"
+#: the committed tile winners (absent until a chip run records some)
+TILES_FILE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                          "tiles.json")
 
+#: set_cache_path's override of TILES_FILE
+_path: Optional[str] = None
 #: lazily-loaded in-process view of the on-disk cache; reset by set_cache_path
 _cache: Optional[dict] = None
 _cache_from: Optional[str] = None
 
 
 def cache_path() -> str:
-    return os.environ.get(_ENV, DEFAULT_CACHE)
+    return _path or TILES_FILE
 
 
 def set_cache_path(path: Optional[str]) -> None:
     """Point the process at a different cache file (tests, benchmarks).
-    ``None`` restores the environment/default resolution."""
-    global _cache, _cache_from
-    if path is None:
-        os.environ.pop(_ENV, None)
-    else:
-        os.environ[_ENV] = path
+    ``None`` restores the committed ``TILES_FILE``."""
+    global _path, _cache, _cache_from
+    _path = path
     _cache, _cache_from = None, None
 
 
@@ -77,11 +78,8 @@ def save_cache(cache: dict, path: Optional[str] = None) -> None:
 
 
 def device_kind() -> str:
-    try:
-        import jax
-        return jax.devices()[0].device_kind.replace(" ", "_")
-    except Exception:
-        return "unknown"
+    import jax
+    return jax.devices()[0].device_kind.replace(" ", "_")
 
 
 def cache_key(op: str, shape: Sequence[int], dtype, kind: str | None = None) -> str:

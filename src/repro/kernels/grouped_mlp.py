@@ -12,9 +12,11 @@ zero-padded to the chosen block multiples (exact under contraction, padded
 output rows/cols sliced off), so ANY block size is legal — no sub-lane tiles
 on prime dims, and the autotuner searches a free grid.
 
-On this CPU container the kernels are validated with ``interpret=True``
-against ``ref.py`` (Pallas does not lower to the CPU backend otherwise);
-``ops.py`` selects the jnp reference path for CPU / dry-run executions.
+On the CPU (``JAX_PLATFORMS=cpu``) the tests validate the kernels with
+``interpret=True`` against ``ref.py`` (Pallas does not lower to the CPU
+backend otherwise); on a TPU they compile for the chip
+(tests/test_tpu_compile.py, ``chip_smoke.py``).  ``ops.py`` selects the jnp
+reference path unless ``use_pallas`` is set.
 """
 
 from __future__ import annotations

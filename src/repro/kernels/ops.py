@@ -1,8 +1,12 @@
 """Jit-friendly wrappers that select the Pallas kernel or the jnp reference.
 
-``use_pallas`` defaults to False because this container (and the dry-run) runs
-on the CPU backend, where Pallas only executes in interpret mode.  On a real
-TPU deployment the launchers pass ``use_pallas=True``.
+``use_pallas`` defaults to False: the CPU backend (the tests, under
+``JAX_PLATFORMS=cpu``, and the dry-run) runs Pallas only in interpret
+mode, which the tests request explicitly.  On a TPU the kernels compile
+for the chip (``--use-pallas`` in the launchers; ``chip_smoke.py`` runs the
+grouped expert FFN compiled), and nothing there passes ``interpret=True``.
+``tests/test_tpu_compile.py`` records which kernels the v5e compiler
+accepts at mixtral widths.
 """
 
 from __future__ import annotations

@@ -15,8 +15,8 @@ Two layers:
   also what lets the measured autotuner (kernels/autotune.py) search the
   full tile space instead of only divisors.
 
-Tile preferences themselves are resolved through the autotuner's on-disk
-cache (docs/DESIGN.md §Autotune): ``resolve_tiles`` returns the measured
+Tile preferences themselves are resolved through the autotuner's committed
+tile file (docs/DESIGN.md §Autotune): ``resolve_tiles`` returns the measured
 winner for ``(op, shape, dtype, device_kind)`` when one is cached, and the
 caller's heuristic defaults otherwise.  Explicit block arguments at a kernel
 call site always win over both.
@@ -80,11 +80,8 @@ def resolve_tiles(op: str, shape: tuple, dtype, defaults: dict,
     tuned for one shape family stays legal on any shape.
     """
     out = dict(defaults)
-    try:  # cache lookups must never break a trace — fall back silently
-        from repro.kernels.autotune import lookup
-        cached = lookup(op, shape, dtype)
-    except Exception:
-        cached = None
+    from repro.kernels.autotune import lookup
+    cached = lookup(op, shape, dtype)
     if cached:
         for k in out:
             if k in cached:

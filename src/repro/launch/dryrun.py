@@ -1,11 +1,14 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: .lower().compile() every (arch x shape x mesh) combo.
 
-The two lines above MUST stay first — jax locks the device count at first
-init, and the production meshes need 512 placeholder host devices.  Smoke
-tests and benches do NOT import this module (they see 1 device).
+The three lines above MUST stay first — jax locks the platform and device
+count at first init, and the production meshes need 512 placeholder CPU
+devices (pinned to the CPU: on a TPU host the dry-run must not take the
+chips).  Smoke tests and benches do NOT import this module (they see 1
+device).
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch mixtral-8x7b \

@@ -25,7 +25,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import (InputShape, ModelConfig, SHAPES, TPU_V5E,
                                 get_config, long_context_eligible)
 from repro.core.mact import MACTController
@@ -112,7 +111,6 @@ def build_context(cfg: ModelConfig, shape: InputShape, mesh: Mesh, *,
         shape.seq_len % shd.axis_size(mesh, "model") == 0 else None
     ctx = DistContext(
         mesh=mesh,
-        batch_axes=shd.batch_axes(mesh),
         ep_axis="model",
         moe_chunks=chunks,
         remat_chunks=True,
@@ -121,12 +119,7 @@ def build_context(cfg: ModelConfig, shape: InputShape, mesh: Mesh, *,
         moe_ragged=bool(flags.get("moe_ragged")),
         moe_fused=bool(flags.get("moe_fused")),
         pallas_interpret=bool(flags.get("pallas_interpret")),
-        act_pspec=NamedSharding(
-            mesh, P(shd.guarded(mesh, B, shd.batch_axes(mesh)), seq_ax, None)),
-        logits_pspec=NamedSharding(mesh, shd.logits_pspec(mesh, B, cfg.padded_vocab)),
-        heads_pspec=NamedSharding(
-            mesh, P(shd.guarded(mesh, B, shd.batch_axes(mesh)), None, "model",
-                    None)),
+        **shd.context_shardings(mesh, cfg, B, seq_ax),
     )
     return cfg, ctx
 
@@ -249,7 +242,7 @@ def lower_combo(arch: str, shape_name: str, mesh: Mesh, *,
             "flags": dict(flags or {}),
             "dtype": str(dtype.__name__ if hasattr(dtype, '__name__') else dtype)}
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.mode == "train":
             state_abs, batch_abs = abstract_train_args(cfg, shape, mesh, dtype,
                                                        flags=flags)
@@ -295,8 +288,6 @@ def analyse(lowered, compiled, hw=TPU_V5E, chips: int = 1) -> dict:
     from repro.launch import hlo_analysis
     ma = compiled.memory_analysis()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):       # old jax: one dict per device
-        ca = ca[0] if ca else {}
     txt = compiled.as_text()
     coll = collective_bytes(txt)
     # scan-aware re-derivation: cost_analysis counts while bodies ONCE, which

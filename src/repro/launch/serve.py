@@ -9,6 +9,12 @@ and the modeled-peak-vs-budget memory headroom.
   PYTHONPATH=src python -m repro.launch.serve --arch mixtral-8x7b --smoke
   PYTHONPATH=src python -m repro.launch.serve --arch gemma3-27b --smoke \
       --requests 16 --arrival-rate 4 --max-slots 4 --budget-gb 32
+
+Weights are built in the config's dtype (bf16 for the published configs),
+and admission plans against the profile of the device JAX reports
+(``configs.base.device_profile``).  On a TPU, ``--layers N`` serves the
+published widths at a cut depth; ``python chip_smoke.py`` drives this path
+at mixtral-8x7b widths on one v5e.
 """
 
 from __future__ import annotations
@@ -41,11 +47,16 @@ def make_trace(rng, n: int, rate_hz: float, prompt_lens, gen_range,
     return out
 
 
-def main() -> None:
+def main(argv=None):
+    """Parse ``argv`` (default: the command line), serve the trace, print
+    the summary; returns the scheduler and its metrics dict."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true",
                     help="reduced config (2 layers, small dims)")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="cut the depth to this many layers, keeping the "
+                         "published widths (0 = the config's depth)")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--arrival-rate", type=float, default=2.0,
                     help="Poisson arrival rate (requests/s); 0 = all at t=0")
@@ -106,14 +117,17 @@ def main() -> None:
                     help="chaos faults on scheduler steps, e.g. 'oom@20' "
                          "(faulted decode waves requeue accepted requests)")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     import dataclasses
 
     import jax
     import numpy as np
     from repro.configs import get_config
-    from repro.configs.base import GPU_64G
+    from repro.configs.base import device_profile
     from repro.core.moe import DistContext
     from repro.models import transformer
     from repro.serving.scheduler import (ContinuousBatchingScheduler,
@@ -122,6 +136,8 @@ def main() -> None:
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.reduced()
+    if args.layers:
+        cfg = dataclasses.replace(cfg, num_layers=args.layers)
     ctx = DistContext()
     replica_bytes = 0.0
     if args.placement_peers:
@@ -144,7 +160,7 @@ def main() -> None:
                        (gen_lo, gen_hi), cfg.vocab_size, args.prefill_chunk)
 
     cache_len = args.cache_len or max(prompt_lens) + gen_hi
-    hw = GPU_64G
+    hw = device_profile()
     if args.budget_gb:
         # the flag names the admission budget itself, so alpha must not
         # discount it a second time
@@ -231,6 +247,7 @@ def main() -> None:
     if sched.finished:
         sample = sched.finished[0]
         print(f"sample (rid {sample.rid}): {sample.out[:12]}")
+    return sched, m
 
 
 if __name__ == "__main__":

@@ -3,9 +3,16 @@
   PYTHONPATH=src python -m repro.launch.train --arch mixtral-8x7b --smoke \
       --steps 50 --seq-len 128 --global-batch 8 [--no-mact] [--chunks 4]
 
-On this CPU container you train the ``--smoke`` reduced variants (the full
-configs are exercised by the dry-run); on a TPU deployment the same launcher
-drives the full config over ``make_production_mesh()`` with --mesh prod.
+On the CPU (``JAX_PLATFORMS=cpu``, what the tests run) train the ``--smoke``
+reduced variants.  On a TPU the same launcher trains published widths:
+``--layers N`` cuts only the depth, and ``--mesh host`` spreads the expert
+group over every chip of the host, e.g. mixtral-8x7b on a 4-chip v5e:
+
+  PYTHONPATH=src python -m repro.launch.train --arch mixtral-8x7b \
+      --layers 1 --mesh host --seq-len 4096 --global-batch 4 --steps 3
+
+``--mesh prod`` / ``prod-mp`` are the 256/512-chip pod meshes of the dry-run.
+``python chip_smoke.py --chips 4`` drives the host-mesh path end to end.
 """
 
 from __future__ import annotations
@@ -15,11 +22,16 @@ import dataclasses
 import json
 
 
-def main() -> None:
+def main(argv=None):
+    """Parse ``argv`` (default: the command line), train, print the summary;
+    returns the ``Trainer`` and its final state."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true",
                     help="train the reduced config (CPU-feasible)")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="cut the depth to this many layers, keeping the "
+                         "published widths (0 = the config's depth)")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--seq-len", type=int, default=128)
     ap.add_argument("--global-batch", type=int, default=8)
@@ -56,7 +68,11 @@ def main() -> None:
                     help="min fractional bottleneck improvement before a "
                          "layer's placement moves (anti-flapping)")
     ap.add_argument("--remat", default=None, choices=["none", "full", "memfine"])
-    ap.add_argument("--mesh", default="local", choices=["local", "prod", "prod-mp"])
+    ap.add_argument("--mesh", default="local",
+                    choices=["local", "host", "prod", "prod-mp"],
+                    help="local: one device; host: (1, n) (data, model) over "
+                         "the n devices present (experts sharded over all); "
+                         "prod/prod-mp: the 256/512-chip pod meshes")
     ap.add_argument("--use-pallas", action="store_true")
     ap.add_argument("--fused", action="store_true",
                     help="single-launch fused MoE expert leg over the ragged "
@@ -75,24 +91,34 @@ def main() -> None:
                     help="chaos faults, e.g. 'oom@3,burst@2x1.5,"
                          "ckpt_truncate@4' (kind@step[xMAG][*TIMES])")
     ap.add_argument("--log-json", default=None)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     import jax
     from repro.configs import get_config
     from repro.core.moe import DistContext
+    from repro.launch import mesh as meshes
     from repro.runtime.faults import FaultInjector
     from repro.training.trainer import Trainer
 
+    if args.fused and jax.devices()[0].platform == "tpu":
+        raise SystemExit("--fused: the fused MoE kernel does not compile for "
+                         "the TPU (tests/test_tpu_compile.py)")
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.reduced()
+    if args.layers:
+        cfg = dataclasses.replace(cfg, num_layers=args.layers)
     if args.remat:
         cfg = dataclasses.replace(cfg, remat_policy=args.remat)
 
     mesh = None
-    if args.mesh != "local":
-        from repro.launch.mesh import make_production_mesh
-        mesh = make_production_mesh(multi_pod=args.mesh == "prod-mp")
+    if args.mesh == "host":
+        mesh = meshes.make_host_mesh()
+    elif args.mesh != "local":
+        mesh = meshes.make_production_mesh(multi_pod=args.mesh == "prod-mp")
     depth = 1 if args.no_pipeline else args.pipeline_depth
     ctx = DistContext(mesh=mesh, moe_chunks=args.chunks,
                       pipeline_chunks=depth if args.no_mact else 1,
@@ -143,6 +169,7 @@ def main() -> None:
     if args.log_json:
         with open(args.log_json, "w") as f:
             json.dump(trainer.log, f, indent=1)
+    return trainer, state
 
 
 if __name__ == "__main__":

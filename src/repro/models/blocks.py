@@ -175,10 +175,14 @@ def attn_mixer_decode(p: dict, x: jax.Array, cache: dict, pos: jax.Array,
     else:
         write = pos
         length = (pos + 1) * jnp.ones((B,), jnp.int32)
-    k_cache = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, write, axis=1)
-    v_cache = jax.lax.dynamic_update_slice_in_dim(cache["v"], v, write, axis=1)
+    # the cache may be wider than the weights (bf16 weights, f32 cache):
+    # write in the cache's dtype, return to the residual stream's
+    k_cache = jax.lax.dynamic_update_slice_in_dim(
+        cache["k"], k.astype(cache["k"].dtype), write, axis=1)
+    v_cache = jax.lax.dynamic_update_slice_in_dim(
+        cache["v"], v.astype(cache["v"].dtype), write, axis=1)
     out = decode_attention(q, k_cache, v_cache, length, spec.attn)
-    y = out.reshape(B, 1, -1) @ p["wo"]
+    y = out.reshape(B, 1, -1).astype(x.dtype) @ p["wo"]
     return y, {"k": k_cache, "v": v_cache}
 
 
@@ -315,7 +319,7 @@ def attn_mixer_extend(p: dict, x: jax.Array, cache: dict, pos0,
     k_cat = jnp.concatenate([cache["k"], k.astype(cache["k"].dtype)], axis=1)
     v_cat = jnp.concatenate([cache["v"], v.astype(cache["v"].dtype)], axis=1)
     out = extend_attention(q, k_cat, v_cat, mask)
-    y = out.reshape(B, C, -1) @ p["wo"]
+    y = out.reshape(B, C, -1).astype(x.dtype) @ p["wo"]
     return y, write_attn_cache(cache, k, v, pos0, spec)
 
 

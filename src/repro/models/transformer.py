@@ -12,6 +12,7 @@ Decode scans the same periods while threading per-period cache slices.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -36,7 +37,23 @@ def _constrain(x, pspec):
 # init
 # ---------------------------------------------------------------------------
 
-def init_params(key: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> dict:
+def init_params(key: jax.Array, cfg: ModelConfig, dtype=None, *,
+                mesh=None) -> dict:
+    """Random weights in ``dtype`` (default ``cfg.dtype``), built under one
+    jit: no eager per-period copies stacked after the fact.  With ``mesh``
+    every leaf is born in its ``param_shardings`` layout, so each device
+    materialises only its own shard."""
+    init = functools.partial(_init_params, cfg=cfg,
+                             dtype=jnp.dtype(cfg.dtype if dtype is None
+                                             else dtype))
+    out_shardings = None
+    if mesh is not None:
+        from repro.distributed.sharding import param_shardings
+        out_shardings = param_shardings(jax.eval_shape(init, key), mesh, cfg)
+    return jax.jit(init, out_shardings=out_shardings)(key)
+
+
+def _init_params(key: jax.Array, cfg: ModelConfig, dtype) -> dict:
     keys = iter(jax.random.split(key, cfg.num_layers + cfg.encoder_layers + 8))
     cross = cfg.encoder_layers > 0
     pattern = cfg.pattern
@@ -57,12 +74,15 @@ def init_params(key: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> dict:
     params["pre"] = [blocks.init_layer(next(keys), spec, cfg, cross, dtype)
                      for spec in cfg.prefix]
     if np_ > 1:
-        per_period = [
-            [blocks.init_layer(next(keys), spec, cfg, cross, dtype)
-             for spec in pattern]
-            for _ in range(np_)
-        ]
-        params["periods"] = jax.tree.map(lambda *xs: jnp.stack(xs), *per_period)
+        # one period per lax.map iteration, written in place into the
+        # stacked leaves: stacking per-period copies (or vmapping the draw)
+        # holds a second full-size copy of the largest leaf
+        def period(k):
+            return [blocks.init_layer(kk, spec, cfg, cross, dtype)
+                    for kk, spec in zip(jax.random.split(k, len(pattern)),
+                                        pattern)]
+        params["periods"] = jax.lax.map(period,
+                                        jax.random.split(next(keys), np_))
     else:
         params["periods"] = None
         rem = cfg.num_layers - len(cfg.prefix)
@@ -302,8 +322,12 @@ def init_cache(params: dict, cfg: ModelConfig, batch_size: int, seq_len: int,
     pattern = cfg.pattern
     cache: dict = {"pos": jnp.int32(0)}
 
-    def layer_cache(spec: LayerSpec, layer_params):
+    def layer_cache(spec: LayerSpec, layer_params, period=None):
         cross = layer_params.get("cross") if isinstance(layer_params, dict) else None
+        if cross is not None and period is not None:
+            # slice only the cross weights out of the stacked periods: an
+            # eager slice of the whole layer copies its expert weights
+            cross = jax.tree.map(lambda a: a[period], cross)
         return blocks.init_layer_cache(spec, cfg, batch_size, seq_len, dtype,
                                        enc_out=enc_out, cross_params=cross)
 
@@ -312,7 +336,7 @@ def init_cache(params: dict, cfg: ModelConfig, batch_size: int, seq_len: int,
     if params["periods"] is not None:
         n = cfg.num_periods
         per_period = [
-            [layer_cache(spec, jax.tree.map(lambda a: a[p], params["periods"][i]))
+            [layer_cache(spec, params["periods"][i], p)
              for i, spec in enumerate(pattern)]
             for p in range(n)
         ]

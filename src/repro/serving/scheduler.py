@@ -36,6 +36,7 @@ never fail.
 
 from __future__ import annotations
 
+import dataclasses
 import time
 from collections import deque
 from dataclasses import dataclass, field
@@ -45,7 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import GPU_64G, HardwareProfile, ModelConfig
+from repro.configs.base import HardwareProfile, ModelConfig, device_profile
 from repro.core import memory_model as mm
 from repro.core.chunking import chunk_spans
 from repro.core.moe import DistContext
@@ -98,7 +99,7 @@ class ServeConfig:
     max_slots: int = 4
     cache_len: int = 128
     prefill_chunk: int = 32
-    hw: HardwareProfile = GPU_64G
+    hw: Optional[HardwareProfile] = None  # None: the device's own profile
     dtype_bytes: int = 2                # modeled cache/act bytes (bf16 target;
                                         # the CPU dry-run holds f32, the model
                                         # describes the production target)
@@ -146,6 +147,8 @@ class ContinuousBatchingScheduler:
         if cfg.encoder_layers or cfg.num_patch_tokens:
             raise ValueError("continuous batching serves token-only decoders; "
                              f"{cfg.name!r} needs per-request encoder state")
+        if scfg.hw is None:
+            scfg = dataclasses.replace(scfg, hw=device_profile())
         self.params, self.cfg, self.ctx, self.scfg = params, cfg, ctx, scfg
         self.key = key if key is not None else jax.random.PRNGKey(0)
         self.queue: deque[Request] = deque()

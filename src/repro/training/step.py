@@ -28,8 +28,34 @@ class TrainState(NamedTuple):
     step: jax.Array
 
 
-def init_train_state(key: jax.Array, cfg: ModelConfig,
-                     dtype=jnp.float32) -> TrainState:
+def init_train_state(key: jax.Array, cfg: ModelConfig, dtype=None, *,
+                     mesh=None) -> TrainState:
+    """Parameters in ``dtype`` (default ``cfg.dtype``) plus zeroed AdamW
+    moments, built under one jit; with ``mesh`` every leaf is born in its
+    ``param_shardings`` layout (moments shard like their parameter)."""
+    init = functools.partial(_init_train_state, cfg=cfg,
+                             dtype=jnp.dtype(cfg.dtype if dtype is None
+                                             else dtype))
+    out_shardings = None
+    if mesh is not None:
+        out_shardings = train_state_shardings(jax.eval_shape(init, key), mesh,
+                                              cfg)
+    return jax.jit(init, out_shardings=out_shardings)(key)
+
+
+def train_state_shardings(state: TrainState, mesh, cfg: ModelConfig):
+    """NamedShardings for a ``TrainState`` (of arrays or shapes): parameters
+    by ``param_shardings``, AdamW moments like their parameter, counters
+    replicated."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.distributed.sharding import param_shardings
+    p_sh = param_shardings(state.params, mesh, cfg)
+    rep = NamedSharding(mesh, P())
+    return TrainState(params=p_sh, opt=AdamWState(step=rep, mu=p_sh, nu=p_sh),
+                      step=rep)
+
+
+def _init_train_state(key: jax.Array, cfg: ModelConfig, dtype) -> TrainState:
     params = transformer.init_params(key, cfg, dtype)
     return TrainState(params=params, opt=adamw_init(params), step=jnp.int32(0))
 

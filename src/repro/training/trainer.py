@@ -32,6 +32,7 @@ bit-identical to a run that never died.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 import warnings
@@ -40,9 +41,11 @@ from dataclasses import dataclass, field
 from typing import Optional
 
 import jax
+import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 
-from repro.configs.base import HardwareProfile, ModelConfig, TPU_V5E
+from repro.configs.base import HardwareProfile, ModelConfig, device_profile
 from repro.core.chunking import ScheduleSpec
 from repro.core.mact import MACTController
 from repro.core.memory_model import Parallelism
@@ -51,6 +54,7 @@ from repro.core import placement as plc
 from repro.core.placement import PlacementSpec
 from repro.core.telemetry import LoadTelemetry
 from repro.data.pipeline import SyntheticLMData
+from repro.distributed import sharding as shd
 from repro.models.transformer import num_moe_layers
 from repro.runtime.faults import FaultInjector
 from repro.runtime.guard import FULL_REMAT, DegradationLadder, OOMGuard
@@ -66,7 +70,7 @@ class Trainer:
     global_batch: int
     lr: float = 3e-4
     seed: int = 0
-    hw: HardwareProfile = TPU_V5E
+    hw: Optional[HardwareProfile] = None   # None: the device's own profile
     par: Optional[Parallelism] = None
     mact_bins: tuple = (1, 2, 4, 8)
     use_mact: bool = True
@@ -106,6 +110,13 @@ class Trainer:
                                          # imbalance, slots migrated, bytes
 
     def __post_init__(self):
+        if self.hw is None:
+            self.hw = device_profile()
+        mesh = self.ctx.mesh
+        if mesh is not None and self.ctx.act_pspec is None:
+            # place activations, logits and heads the way the dry-run does
+            self.ctx = dataclasses.replace(self.ctx, **shd.context_shardings(
+                mesh, self.cfg, self.global_batch))
         if self.par is None:
             ep = data = 1
             if self.ctx.mesh is not None:
@@ -411,13 +422,29 @@ class Trainer:
         step = checkpointing.latest_step(self.checkpoint_dir)
         if step is None:
             return None
-        like = init_train_state(jax.random.PRNGKey(self.seed), self.cfg)
+        like = self._init_state()
         state = checkpointing.restore(self.checkpoint_dir, step, like)
         self._apply_extra(checkpointing.load_extra(self.checkpoint_dir, step))
         self.resumed_from = step
         return state
 
     # -- main loop ---------------------------------------------------------------
+    def _init_state(self) -> TrainState:
+        """float32 master weights (and moments), each leaf born in its
+        ``param_shardings`` layout when the ctx carries a mesh."""
+        return init_train_state(jax.random.PRNGKey(self.seed), self.cfg,
+                                jnp.float32, mesh=self.ctx.mesh)
+
+    def _place_batch(self, batch: dict) -> dict:
+        mesh = self.ctx.mesh
+        if mesh is None:
+            return {k: jnp.asarray(v) for k, v in batch.items()}
+        return {k: jax.device_put(v, NamedSharding(
+                    mesh, shd.batch_pspec(mesh, self.global_batch)
+                    if v.ndim == 2 else
+                    shd.act_pspec(mesh, self.global_batch)))
+                for k, v in batch.items()}
+
     def fit(self, steps: int, state: Optional[TrainState] = None,
             verbose: bool = False) -> TrainState:
         """Run the training loop.
@@ -428,16 +455,21 @@ class Trainer:
         crash + re-run converges on the same final step as an uninterrupted
         run.
         """
+        mesh = self.ctx.mesh
+        with jax.set_mesh(mesh) if mesh is not None else contextlib.nullcontext():
+            return self._fit(steps, state, verbose)
+
+    def _fit(self, steps: int, state: Optional[TrainState],
+             verbose: bool) -> TrainState:
         if state is None and self.resume and self.checkpoint_dir:
             state = self._resume_state()
         if state is None:
-            state = init_train_state(jax.random.PRNGKey(self.seed), self.cfg)
+            state = self._init_state()
         n = steps - int(state.step) if self.resume else steps
         for i in range(max(n, 0)):
             step_idx = int(state.step)
             key = self._next_schedule_key()
-            batch = {k: jax.numpy.asarray(v)
-                     for k, v in self.data.batch_at(step_idx).items()}
+            batch = self._place_batch(self.data.batch_at(step_idx))
 
             def attempt(k, _state=state, _batch=batch, _step=step_idx):
                 if self.injector is not None:

@@ -2,44 +2,15 @@
 see the 1 real device; multi-device tests spawn subprocesses that set
 --xla_force_host_platform_device_count themselves."""
 
-import sys
-import types
+import os
 
-import jax
-import pytest
+# The launchers turn JAX's persistent compilation cache on
+# (repro/launch/compile_cache.py); the tests, and the launcher subprocesses
+# they start, never write it.
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 
-# ---------------------------------------------------------------------------
-# hypothesis fallback: when the package is absent, install a stub whose
-# @given marks the property test as skipped instead of failing collection of
-# the whole module (the non-property tests in those modules still run).
-# ---------------------------------------------------------------------------
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    def _strategy(*args, **kwargs):
-        return None
-
-    def _given(*args, **kwargs):
-        def deco(fn):
-            return pytest.mark.skip(
-                reason="hypothesis not installed — property test skipped")(fn)
-        return deco
-
-    def _settings(*args, **kwargs):
-        return lambda fn: fn
-
-    _st = types.ModuleType("hypothesis.strategies")
-    for _name in ("integers", "floats", "sampled_from", "booleans", "lists",
-                  "tuples", "one_of", "just", "text", "composite"):
-        setattr(_st, _name, _strategy)
-    _hyp = types.ModuleType("hypothesis")
-    _hyp.given = _given
-    _hyp.settings = _settings
-    _hyp.strategies = _st
-    _hyp.HealthCheck = types.SimpleNamespace(too_slow=None, data_too_large=None)
-    _hyp.assume = lambda *a, **k: True
-    sys.modules["hypothesis"] = _hyp
-    sys.modules["hypothesis.strategies"] = _st
+import jax  # noqa: E402
+import pytest  # noqa: E402
 
 
 @pytest.fixture(scope="session")

@@ -13,6 +13,7 @@ from repro.core import dispatch as dsp
 from repro.core import moe as M
 from repro.kernels import dispatch_pallas as dp
 from repro.kernels import ops, ref
+from repro.launch.mesh import make_mesh
 
 
 def _exact_case(seed, T=24, K=2, E=4, d=16, bm=8):
@@ -118,8 +119,7 @@ def test_moe_grad_parity_vs_dense_oracle(top_k):
     params = M.init_moe(jax.random.PRNGKey(0), 16, cfg)
     params = _uneven_router(params, cfg.num_experts)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, 16))
-    mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1),
-                             ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     ctx_pallas = M.DistContext(mesh=mesh, moe_chunks=2,
                                moe_strategy="ep_shardmap", moe_ragged=True,
                                use_pallas=True, pallas_interpret=True)

@@ -28,15 +28,15 @@ def run_py(body: str, devices: int = 8, timeout: int = 600) -> str:
 def test_ep_shardmap_equals_tp_path():
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
-        from repro.compat import set_mesh
         from repro.core import moe as M
         from repro.configs.base import MoEConfig
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 2), ("data", "model"))
         cfg = MoEConfig(num_experts=4, top_k=2, d_ff_expert=64)
         params = M.init_moe(jax.random.PRNGKey(0), 32, cfg)
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, 32))
         ctx_ep = M.DistContext(mesh=mesh, moe_chunks=2, moe_strategy="ep_shardmap")
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             y_ep, s_ep = jax.jit(lambda p, x: M.moe_ffn(p, x, cfg, ctx_ep))(params, x)
             g_ep = jax.jit(jax.grad(lambda p: M.moe_ffn(p, x, cfg, ctx_ep)[0].sum()))(params)
         y_tp, s_tp = M.moe_ffn(params, x, cfg, M.DistContext(moe_chunks=2))
@@ -55,14 +55,14 @@ def test_ep_shardmap_equals_tp_path():
 def test_ep_chunk_invariance_on_mesh():
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
-        from repro.compat import set_mesh
         from repro.core import moe as M
         from repro.configs.base import MoEConfig
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = MoEConfig(num_experts=8, top_k=2, d_ff_expert=32)
         params = M.init_moe(jax.random.PRNGKey(0), 16, cfg)
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, 16))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             outs = []
             for c in (1, 2, 4):
                 ctx = M.DistContext(mesh=mesh, moe_chunks=c, moe_strategy="ep_shardmap")
@@ -81,14 +81,14 @@ def test_ep_pipelined_schedule_on_mesh():
     and gradients — with remat on and off."""
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
-        from repro.compat import set_mesh
         from repro.core import moe as M
         from repro.configs.base import MoEConfig
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = MoEConfig(num_experts=8, top_k=2, d_ff_expert=32)
         params = M.init_moe(jax.random.PRNGKey(0), 16, cfg)
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, 16))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             for remat in (True, False):
                 ctx0 = M.DistContext(mesh=mesh, moe_chunks=4, remat_chunks=remat,
                                      moe_strategy="ep_shardmap")
@@ -114,14 +114,14 @@ def test_full_train_step_on_mesh():
     and produces finite loss on a 2x4 mesh."""
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
-        from repro.compat import set_mesh
         from dataclasses import replace
         from repro.configs import get_config
         from repro.launch import dryrun_lib as lib
         from repro.configs.base import InputShape
         from repro.training.step import init_train_state, make_train_step
         from repro.data.pipeline import SyntheticLMData
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = replace(get_config("mixtral-8x7b").reduced(),
                       moe=replace(get_config("mixtral-8x7b").reduced().moe,
                                   num_experts=4))
@@ -130,7 +130,7 @@ def test_full_train_step_on_mesh():
         state = init_train_state(jax.random.PRNGKey(0), cfg)
         data = SyntheticLMData(cfg, 32, 4)
         batch = {k: jnp.asarray(v) for k, v in data.batch_at(0).items()}
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             step = jax.jit(make_train_step(cfg, ctx, lr=1e-3))
             state, m = step(state, batch)
             state, m = step(state, batch)
@@ -146,8 +146,8 @@ def test_dryrun_small_mesh_lowers_and_compiles():
     out = run_py("""
         import jax, jax.numpy as jnp
         from repro.launch import dryrun_lib as lib
-        from repro.launch.mesh import make_host_mesh
-        mesh = make_host_mesh((2, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 2), ("data", "model"))
         for arch, shape in [("mixtral-8x7b", "train_4k"),
                             ("gemma3-27b", "prefill_32k"),
                             ("mamba2-130m", "decode_32k")]:
@@ -178,10 +178,10 @@ def test_ragged_ep_equals_per_expert_ep():
     identical outputs/grads to the per-expert buffer EP path on a mesh."""
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
-        from repro.compat import set_mesh
         from repro.core import moe as M
         from repro.configs.base import MoEConfig
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 2), ("data", "model"))
         cfg = MoEConfig(num_experts=4, top_k=2, d_ff_expert=64)
         params = M.init_moe(jax.random.PRNGKey(0), 32, cfg)
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, 32))
@@ -195,7 +195,7 @@ def test_ragged_ep_equals_per_expert_ep():
                                          pallas_interpret=True),
         }
         ys = {}
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             for name, ctx in ctxs.items():
                 y, s = jax.jit(lambda p, x, c=ctx: M.moe_ffn(p, x, cfg, c))(params, x)
                 ys[name] = np.asarray(y)

@@ -28,6 +28,7 @@ from repro.kernels.fused_moe import fused_moe
 from repro.kernels.ops import (combine_rows, dispatch_rows, moe_ffn,
                                ragged_expert_ffn)
 from repro.kernels.tiling import resolve_tiles
+from repro.launch.mesh import make_mesh
 
 
 # ---------------------------------------------------------------------------
@@ -246,8 +247,7 @@ def test_moe_layer_fused_matches_ragged():
     cfg = MoEConfig(num_experts=4, top_k=2, d_ff_expert=32)
     params = M.init_moe(jax.random.PRNGKey(0), 16, cfg)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, 16))
-    mesh = jax.sharding.Mesh(
-        np.asarray(jax.devices()[:1]).reshape(1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     y_rg, s_rg = M.moe_ffn(params, x, cfg, M.DistContext(
         mesh=mesh, moe_strategy="ep_shardmap", moe_chunks=2,
         moe_ragged=True))

@@ -61,8 +61,8 @@ def test_collectives_counted_with_trips():
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.launch import hlo_analysis as H
-        from repro.compat import set_mesh
-        mesh = jax.make_mesh((4,), ('m',))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ('m',))
         def f(x):
             def body(c, _):
                 s = jax.lax.with_sharding_constraint(c.sum(0, keepdims=True),
@@ -72,7 +72,7 @@ def test_collectives_counted_with_trips():
             return y.sum()
         xs = jax.ShapeDtypeStruct((16, 64), jnp.float32,
                                   sharding=NamedSharding(mesh, P('m', None)))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             txt = jax.jit(f).lower(xs).compile().as_text()
         r = H.analyse_module(txt)
         print('COLL', r['collective_total'])

@@ -129,3 +129,16 @@ def test_params_active_vs_total():
     active = mm.active_params(cfg)
     assert 40e9 < total < 52e9       # Mixtral ~47B
     assert 10e9 < active < 16e9      # ~13B active
+
+
+@pytest.mark.parametrize("kind,want", [("TPU v5 lite", TPU_V5E),
+                                       ("cpu", TPU_V5E), ("TPU v4", None)])
+def test_device_profile_by_device_kind(kind, want):
+    """The memory model plans against the device JAX reports; a kind with
+    no profile is an error, never a default."""
+    from repro.configs import device_profile
+    if want is None:
+        with pytest.raises(ValueError, match="no hardware profile"):
+            device_profile(kind)
+    else:
+        assert device_profile(kind) is want

@@ -169,6 +169,21 @@ def test_decode_matches_forward(arch):
                                np.asarray(full[:, -1]), rtol=2e-4, atol=2e-4)
 
 
+def test_init_cache_reads_no_decoder_weights():
+    """A decoder-only cache is zeros: building it reads no weight.  Slicing
+    whole stacked periods (to find cross-attention weights) copied each
+    period's expert weights, a transient weight-sized peak on the chip."""
+    cfg = dataclasses.replace(registry()["mixtral-8x7b"].reduced(),
+                              num_layers=4)
+    params = jax.eval_shape(
+        lambda k: transformer.init_params(k, cfg), jax.random.PRNGKey(0))
+    assert params["periods"] is not None
+    jaxpr = jax.make_jaxpr(lambda p: transformer.init_cache(
+        p, cfg, 2, 16, jnp.float32))(params).jaxpr
+    read = {id(v) for eqn in jaxpr.eqns for v in eqn.invars}
+    assert not read & {id(v) for v in jaxpr.invars}
+
+
 def test_remat_policies_same_loss():
     from repro.training.step import loss_fn
     cfg = registry()["mixtral-8x7b"].reduced()

@@ -11,6 +11,7 @@ import pytest
 from repro.configs.base import MoEConfig
 from repro.core import dispatch as dsp
 from repro.core import moe as M
+from repro.launch.mesh import make_mesh
 
 CFG = MoEConfig(num_experts=4, top_k=2, d_ff_expert=32)
 
@@ -23,8 +24,7 @@ def setup():
 
 
 def _mesh11():
-    return jax.sharding.Mesh(
-        np.asarray(jax.devices()[:1]).reshape(1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def _ctx(strategy, **kw):

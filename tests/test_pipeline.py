@@ -14,6 +14,7 @@ from repro.core import moe as M
 from repro.core.chunking import ChunkStages, chunked_map, chunked_pipeline, compose
 from repro.core.mact import MACTController
 from repro.core.moe import DistContext
+from repro.launch.mesh import make_mesh
 
 CFG = MoEConfig(num_experts=4, top_k=2, d_ff_expert=64)
 CAP_CFG = MoEConfig(num_experts=4, top_k=2, d_ff_expert=64,
@@ -105,7 +106,7 @@ def test_pipeline_depth_fallbacks(toy):
 
 @pytest.fixture(scope="module")
 def ep_setup():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     params = M.init_moe(jax.random.PRNGKey(0), 32, CFG)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, 32))
     return mesh, params, x
@@ -113,8 +114,7 @@ def ep_setup():
 
 def _run(mesh, params, x, cfg, **ctx_kw):
     ctx = DistContext(mesh=mesh, moe_strategy="ep_shardmap", **ctx_kw)
-    from repro.compat import set_mesh
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         return jax.jit(lambda p, x: M.moe_ffn(p, x, cfg, ctx))(params, x)
 
 
@@ -148,7 +148,6 @@ def test_ep_pipeline_parity_capacity_mode(ep_setup):
 @pytest.mark.parametrize("c", [2, 8])
 def test_ep_pipeline_gradient_parity(ep_setup, c):
     mesh, params, x = ep_setup
-    from repro.compat import set_mesh
 
     def loss(p, ctx):
         return M.moe_ffn(p, x, CFG, ctx)[0].sum()
@@ -156,7 +155,7 @@ def test_ep_pipeline_gradient_parity(ep_setup, c):
     ctx0 = DistContext(mesh=mesh, moe_strategy="ep_shardmap", moe_chunks=c)
     ctx1 = DistContext(mesh=mesh, moe_strategy="ep_shardmap", moe_chunks=c,
                        pipeline_chunks=2)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         g0 = jax.jit(jax.grad(lambda p: loss(p, ctx0)))(params)
         g1 = jax.jit(jax.grad(lambda p: loss(p, ctx1)))(params)
     for a, b in zip(jax.tree.leaves(g0), jax.tree.leaves(g1)):

@@ -346,12 +346,12 @@ def test_ep_placement_bit_parity_forward_and_grads():
     leaves are equal only to float-reassociation tolerance."""
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
-        from repro.compat import set_mesh
         from repro.core import moe as M
         from repro.core import placement as plc
         from repro.core.placement import PlacementSpec
         from repro.configs.base import MoEConfig
-        mesh = jax.make_mesh((1, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((1, 4), ("data", "model"))
         cfg = MoEConfig(num_experts=8, top_k=2, d_ff_expert=64)
         params = M.init_moe(jax.random.PRNGKey(0), 32, cfg)
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, 32))
@@ -362,7 +362,7 @@ def test_ep_placement_bit_parity_forward_and_grads():
             def loss(p, xx):
                 y, s = M.moe_ffn(p, xx, cfg, ctx)
                 return (y ** 2).sum(), (y, s)
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 (l, (y, s)), g = jax.jit(jax.value_and_grad(
                     loss, argnums=(0, 1), has_aux=True))(params, x)
             return l, y, s, g
@@ -407,11 +407,11 @@ def test_ep_placement_all_to_one_routing_round_trip():
     runs identical (the replica split is deterministic)."""
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
-        from repro.compat import set_mesh
         from repro.core import moe as M
         from repro.core import placement as plc
         from repro.configs.base import MoEConfig
-        mesh = jax.make_mesh((1, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((1, 4), ("data", "model"))
         cfg = MoEConfig(num_experts=8, top_k=2, d_ff_expert=32)
         params = M.init_moe(jax.random.PRNGKey(0), 16, cfg)
         # force the router: zero weights -> uniform scores -> top-k
@@ -428,7 +428,7 @@ def test_ep_placement_all_to_one_routing_round_trip():
             ctx = M.DistContext(mesh=mesh, moe_chunks=2,
                                 moe_strategy="ep_shardmap",
                                 placement=placement)
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 y, s = jax.jit(lambda p, xx: M.moe_ffn(p, xx, cfg, ctx))(params, x)
             return np.asarray(y), s
         y0, s0 = run(None)
@@ -453,12 +453,12 @@ def test_migration_then_step_equals_cold_start_on_mesh():
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
         from dataclasses import replace
-        from repro.compat import set_mesh
         from repro.configs import get_config
         from repro.core.moe import DistContext
         from repro.training.step import init_train_state
         from repro.training.trainer import Trainer
-        mesh = jax.make_mesh((1, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((1, 4), ("data", "model"))
         cfg = get_config("mixtral-8x7b").reduced()
         cfg = replace(cfg, moe=replace(cfg.moe, num_experts=8))
         ctx = DistContext(mesh=mesh, moe_chunks=2, moe_strategy="ep_shardmap")
@@ -472,7 +472,7 @@ def test_migration_then_step_equals_cold_start_on_mesh():
         trA, skew = make()
         state = init_train_state(jax.random.PRNGKey(0), cfg)
         batch = trA.data.batch_at(0)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             k0 = trA._with_placements(trA._next_schedule_key())
             s0, m0 = trA._compiled(k0)(state, batch)
             trA.telemetry.update(skew)
@@ -482,7 +482,7 @@ def test_migration_then_step_equals_cold_start_on_mesh():
         # trainer B: cold start straight at the same placement
         trB, _ = make()
         trB.telemetry.update(skew)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             kB = trB._with_placements(trB._next_schedule_key())
             assert trB._placements == trA._placements
             sB, mB = trB._compiled(kB)(s0, batch)

@@ -16,7 +16,8 @@ def test_param_shardings_guarded():
         from repro.configs import get_config
         from repro.distributed import sharding as shd
         from repro.models import transformer
-        mesh = jax.make_mesh((2, 8), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 8), ("data", "model"))
         for arch in ("mixtral-8x7b", "whisper-small", "jamba-1.5-large-398b"):
             cfg = get_config(arch).reduced()
             params = jax.eval_shape(
