@@ -129,7 +129,8 @@ def _moe_ffn_rows(params: dict, x: jax.Array, cfg: MoEConfig,
     def row_fn(xrow):
         def chunk_fn(xc):
             t_c = xc.shape[0]
-            r = route(params["router"], xc, cfg)
+            with jax.named_scope("router"):
+                r = route(params["router"], xc, cfg)
             if cfg.capacity_mode == "dropless":
                 cap = dsp.dropless_capacity(t_c)
             else:
@@ -140,13 +141,17 @@ def _moe_ffn_rows(params: dict, x: jax.Array, cfg: MoEConfig,
             # over batch rows and the Pallas dispatch kernels want the
             # un-vmapped flat layout (the EP path is where chunked dispatch
             # overhead actually bites); the expert FFN honors use_pallas.
-            uplan = dsp.make_unified_plan(r.expert_idx, E, 1, cap_expert=cap)
-            plan = dsp.DispatchPlan(uplan.expert_slots, uplan.expert_load,
-                                    uplan.drops_expert)
-            buf = dsp.scatter_rows(xc, plan, E, cap)
-            h = expert_ffn(buf, params["w1"], params["w3"], params["w2"],
-                           use_pallas=ctx.use_pallas)
-            y = dsp.gather_rows(h, plan, r.weights)
+            with jax.named_scope("dispatch"):
+                uplan = dsp.make_unified_plan(r.expert_idx, E, 1,
+                                              cap_expert=cap)
+                plan = dsp.DispatchPlan(uplan.expert_slots,
+                                        uplan.expert_load, uplan.drops_expert)
+                buf = dsp.scatter_rows(xc, plan, E, cap)
+            with jax.named_scope("experts"):
+                h = expert_ffn(buf, params["w1"], params["w3"], params["w2"],
+                               use_pallas=ctx.use_pallas)
+            with jax.named_scope("combine"):
+                y = dsp.gather_rows(h, plan, r.weights)
             stats = {"aux_loss": r.aux_loss,
                      "load": r.load.astype(jnp.float32),
                      "drops": plan.drops.astype(jnp.float32)}
@@ -171,13 +176,16 @@ def _moe_ffn_dense(params: dict, x: jax.Array, cfg: MoEConfig,
                    ctx: DistContext):
     B, S, d = x.shape
     x2 = x.reshape(B * S, d)
-    r = route(params["router"], x2, cfg)
-    xe = jnp.broadcast_to(x2[None], (cfg.num_experts,) + x2.shape)
-    h = expert_ffn(xe, params["w1"], params["w3"], params["w2"],
-                   use_pallas=False)                       # (E, T, d)
-    onehot = jax.nn.one_hot(r.expert_idx, cfg.num_experts, dtype=h.dtype)
-    w = (onehot * r.weights[..., None].astype(h.dtype)).sum(1)   # (T, E)
-    y = jnp.einsum("te,etd->td", w, h)
+    with jax.named_scope("router"):
+        r = route(params["router"], x2, cfg)
+    with jax.named_scope("experts"):
+        xe = jnp.broadcast_to(x2[None], (cfg.num_experts,) + x2.shape)
+        h = expert_ffn(xe, params["w1"], params["w3"], params["w2"],
+                       use_pallas=False)                   # (E, T, d)
+    with jax.named_scope("combine"):
+        onehot = jax.nn.one_hot(r.expert_idx, cfg.num_experts, dtype=h.dtype)
+        w = (onehot * r.weights[..., None].astype(h.dtype)).sum(1)  # (T, E)
+        y = jnp.einsum("te,etd->td", w, h)
     stats = {"aux_loss": r.aux_loss, "load": r.load.astype(jnp.float32),
              "drops": jnp.float32(0)}
     return y.reshape(B, S, d), stats
@@ -232,5 +240,6 @@ def moe_ffn(params: dict, x: jax.Array, cfg: MoEConfig, ctx: DistContext):
     else:
         raise ValueError(f"unknown MoE strategy {strategy!r}")
     if "shared" in params:
-        y = y + _shared_expert(params, x)
+        with jax.named_scope("experts"):
+            y = y + _shared_expert(params, x)
     return y, stats

@@ -345,15 +345,16 @@ def apply_layer(params: dict, x: jax.Array, spec: LayerSpec, cfg: ModelConfig,
         cache: dict = {}
         h = apply_norm(params["norm1"], x, cfg.norm)
         if spec.mixer == "attn":
-            if build_cache:
-                h, (k_raw, v_raw) = attn_mixer(params["mixer"], h, cfg, spec,
-                                               positions, ctx, causal,
-                                               return_kv=True)
-                cache["attn"] = build_attn_cache(k_raw, v_raw, spec,
-                                                 cache_len, cache_dtype)
-            else:
-                h = attn_mixer(params["mixer"], h, cfg, spec, positions, ctx,
-                               causal)
+            with jax.named_scope("attention"):
+                if build_cache:
+                    h, (k_raw, v_raw) = attn_mixer(
+                        params["mixer"], h, cfg, spec, positions, ctx, causal,
+                        return_kv=True)
+                    cache["attn"] = build_attn_cache(k_raw, v_raw, spec,
+                                                     cache_len, cache_dtype)
+                else:
+                    h = attn_mixer(params["mixer"], h, cfg, spec, positions,
+                                   ctx, causal)
         else:
             if build_cache:
                 h, state = ssm_mod.apply_ssm(params["mixer"], h, spec.ssm,
@@ -377,7 +378,8 @@ def apply_layer(params: dict, x: jax.Array, spec: LayerSpec, cfg: ModelConfig,
             if spec.ffn == "dense":
                 h = apply_mlp(params["ffn"], h)
             else:
-                h, stats = moe_ffn(params["ffn"], h, cfg.moe, ctx)
+                with jax.named_scope("moe"):
+                    h, stats = moe_ffn(params["ffn"], h, cfg.moe, ctx)
             x = x + h
         if build_cache:
             return x, stats, cache
@@ -417,8 +419,9 @@ def apply_layer_decode(params: dict, x: jax.Array, cache, spec: LayerSpec,
     default path is unchanged."""
     h = apply_norm(params["norm1"], x, cfg.norm)
     if spec.mixer == "attn":
-        h, new_attn = attn_mixer_decode(params["mixer"], h, cache["attn"], pos,
-                                        cfg, spec, ctx)
+        with jax.named_scope("attention"):
+            h, new_attn = attn_mixer_decode(params["mixer"], h, cache["attn"],
+                                            pos, cfg, spec, ctx)
         cache = {**cache, "attn": new_attn}
     else:
         h, new_state = ssm_mod.decode_ssm(params["mixer"], h,
@@ -441,7 +444,8 @@ def apply_layer_decode(params: dict, x: jax.Array, cache, spec: LayerSpec,
         if spec.ffn == "dense":
             h = apply_mlp(params["ffn"], h)
         else:
-            h, st = moe_ffn(params["ffn"], h, cfg.moe, ctx)
+            with jax.named_scope("moe"):
+                h, st = moe_ffn(params["ffn"], h, cfg.moe, ctx)
             load = st["load"].astype(jnp.float32)
         x = x + h
     if return_load:
@@ -462,8 +466,9 @@ def apply_layer_extend(params: dict, x: jax.Array, cache, spec: LayerSpec,
     B, C, _ = x.shape
     h = apply_norm(params["norm1"], x, cfg.norm)
     if spec.mixer == "attn":
-        h, new_attn = attn_mixer_extend(params["mixer"], h, cache["attn"],
-                                        pos0, cfg, spec, ctx)
+        with jax.named_scope("attention"):
+            h, new_attn = attn_mixer_extend(params["mixer"], h, cache["attn"],
+                                            pos0, cfg, spec, ctx)
         cache = {**cache, "attn": new_attn}
     else:
         h, new_state = ssm_mod.apply_ssm(
@@ -487,7 +492,8 @@ def apply_layer_extend(params: dict, x: jax.Array, cache, spec: LayerSpec,
         if spec.ffn == "dense":
             h = apply_mlp(params["ffn"], h)
         else:
-            h, st = moe_ffn(params["ffn"], h, cfg.moe, ctx)
+            with jax.named_scope("moe"):
+                h, st = moe_ffn(params["ffn"], h, cfg.moe, ctx)
             load = st["load"].astype(jnp.float32)
         x = x + h
     if return_load:

@@ -119,9 +119,10 @@ def embed_inputs(params: dict, cfg: ModelConfig, batch: dict) -> jax.Array:
 
 
 def unembed(params: dict, cfg: ModelConfig, x: jax.Array) -> jax.Array:
-    x = apply_norm(params["final_norm"], x, cfg.norm)
-    head = params["embed"].T if cfg.tie_embeddings else params["head"]
-    return (x @ head).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        x = apply_norm(params["final_norm"], x, cfg.norm)
+        head = params["embed"].T if cfg.tie_embeddings else params["head"]
+        return (x @ head).astype(jnp.float32)
 
 
 # ---------------------------------------------------------------------------

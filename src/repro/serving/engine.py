@@ -121,9 +121,9 @@ def _jit(fn, donate_cache_arg: Optional[int] = None):
 def get_decode_step(cfg: ModelConfig, ctx: DistContext):
     """The compiled single-token step(params, cache, tokens (B,1))."""
     def build():
-        def fn(params, cache, tokens):
+        def serve_decode_step(params, cache, tokens):
             return transformer.decode_step(params, cfg, ctx, cache, tokens)
-        return _jit(fn, donate_cache_arg=1)
+        return _jit(serve_decode_step, donate_cache_arg=1)
     return _cached(("decode", cfg, ctx), build)
 
 
@@ -131,9 +131,9 @@ def get_extend_step(cfg: ModelConfig, ctx: DistContext):
     """The compiled chunk step(params, cache, tokens (B,C)) — chunked
     prefill continuation."""
     def build():
-        def fn(params, cache, tokens):
+        def serve_extend_chunk(params, cache, tokens):
             return transformer.extend_step(params, cfg, ctx, cache, tokens)
-        return _jit(fn, donate_cache_arg=1)
+        return _jit(serve_extend_chunk, donate_cache_arg=1)
     return _cached(("extend", cfg, ctx), build)
 
 
@@ -143,12 +143,12 @@ def get_prefill_fn(cfg: ModelConfig, ctx: DistContext, cache_len: int,
     dtype = jnp.dtype(dtype)
 
     def build():
-        def fn(params, batch):
+        def serve_prefill_chunk(params, batch):
             logits, _stats, cache = transformer.forward(
                 params, cfg, ctx, batch, return_cache=True,
                 cache_len=cache_len, cache_dtype=dtype)
             return logits[:, -1:], cache
-        return _jit(fn)
+        return _jit(serve_prefill_chunk)
     return _cached(("prefill", cfg, ctx, cache_len, dtype.name), build)
 
 
@@ -173,7 +173,7 @@ def get_decode_step_masked(cfg: ModelConfig, ctx: DistContext):
     exactly, which is what makes grouped waves equivalent to FIFO waves.
     Non-member load rows are zeroed so layer unions only see members."""
     def build():
-        def fn(params, cache, tokens, mask):
+        def serve_decode_masked(params, cache, tokens, mask):
             logits, new_cache, load = jax.vmap(
                 lambda c, t: transformer.decode_step(
                     params, cfg, ctx, c, t, return_load=True),
@@ -185,7 +185,7 @@ def get_decode_step_masked(cfg: ModelConfig, ctx: DistContext):
             out_cache = jax.tree_util.tree_map(keep, new_cache, cache)
             load = load * mask.astype(load.dtype)[:, None, None]
             return logits, out_cache, load
-        return _jit(fn)
+        return _jit(serve_decode_masked)
     return _cached(("decode_masked", cfg, ctx), build)
 
 
@@ -193,10 +193,10 @@ def get_extend_step_loads(cfg: ModelConfig, ctx: DistContext):
     """Compiled chunk step that also reports the (L_moe, E) routed load —
     step(params, cache, tokens (B,C)) -> (logits (B,C,V), cache, load)."""
     def build():
-        def fn(params, cache, tokens):
+        def serve_extend_chunk_loads(params, cache, tokens):
             return transformer.extend_step(params, cfg, ctx, cache, tokens,
                                            return_load=True)
-        return _jit(fn)
+        return _jit(serve_extend_chunk_loads)
     return _cached(("extend_loads", cfg, ctx), build)
 
 
@@ -206,7 +206,7 @@ def get_prefill_fn_loads(cfg: ModelConfig, ctx: DistContext, cache_len: int,
     dtype = jnp.dtype(dtype)
 
     def build():
-        def fn(params, batch):
+        def serve_prefill_chunk_loads(params, batch):
             logits, stats, cache = transformer.forward(
                 params, cfg, ctx, batch, return_cache=True,
                 cache_len=cache_len, cache_dtype=dtype)
@@ -215,7 +215,7 @@ def get_prefill_fn_loads(cfg: ModelConfig, ctx: DistContext, cache_len: int,
             else:
                 load = jnp.zeros((0, 1), jnp.float32)
             return logits[:, -1:], cache, load
-        return _jit(fn)
+        return _jit(serve_prefill_chunk_loads)
     return _cached(("prefill_loads", cfg, ctx, cache_len, dtype.name), build)
 
 
@@ -236,7 +236,7 @@ def get_router_probe(cfg: ModelConfig, ctx: DistContext):
     refs = moe_layer_refs(cfg)
 
     def build():
-        def fn(params, tokens):
+        def serve_router_probe(params, tokens):
             x = jnp.take(params["embed"], tokens, axis=0)
             x = x.astype(params["embed"].dtype)
             E = cfg.moe.num_experts
@@ -252,7 +252,7 @@ def get_router_probe(cfg: ModelConfig, ctx: DistContext):
             if not per_layer:
                 return jnp.zeros((tokens.shape[0], 0, 1), jnp.float32)
             return jnp.stack(per_layer, axis=1)                # (N, L, E)
-        return _jit(fn)
+        return _jit(serve_router_probe)
     return _cached(("router_probe", cfg, ctx), build)
 
 

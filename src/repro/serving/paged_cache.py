@@ -38,6 +38,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.core.moe import DistContext
@@ -233,44 +234,54 @@ class PagedCachePool:
 
     # -- compiled steps ------------------------------------------------------
 
-    def _build_decode(self):
-        cfg, ctx = self.cfg, self.ctx
-        infos, treedef = self.layout.leaves, self.layout.treedef
-        gidx, page = self._gidx, self.page
-
-        def fn(params, pools, gt, st, sg, ss, pos, toks):
-            leaves = []
-            for i, info in enumerate(infos):
+    def _gather_slots(self, pools, gt, sg, pos):
+        """Dense per-slot caches (batch axis leading) read through the page
+        tables ``gt`` and state ids ``sg``: a decode wave's input."""
+        leaves = []
+        with jax.named_scope("paged_gather"):
+            for i, info in enumerate(self.layout.leaves):
                 if info.kind == "pos":
                     leaves.append(pos)
                 elif info.kind == "token":
                     x = blocks.gather_paged_tokens(
-                        pools[i], gt[gidx[info.group]], info.token_axis,
+                        pools[i], gt[self._gidx[info.group]], info.token_axis,
                         info.group.length)
                     leaves.append(jnp.expand_dims(x, 1 + info.batch_axis))
                 else:
                     leaves.append(jnp.expand_dims(pools[i][sg],
                                                   1 + info.batch_axis))
-            cache = jax.tree_util.tree_unflatten(treedef, leaves)
-            logits, new_cache = jax.vmap(
-                lambda c, t: transformer.decode_step(params, cfg, ctx, c, t),
-                in_axes=(0, 0))(cache, toks)
-            new_leaves = jax.tree_util.tree_flatten(new_cache)[0]
-            new_pools = []
-            for i, info in enumerate(infos):
+        return jax.tree_util.tree_unflatten(self.layout.treedef, leaves)
+
+    def _scatter_slots(self, pools, new_cache, st, ss) -> tuple:
+        """The pools with a wave's per-slot caches written back through the
+        scatter tables ``st`` and state ids ``ss``."""
+        new_leaves = jax.tree_util.tree_flatten(new_cache)[0]
+        new_pools = []
+        with jax.named_scope("paged_scatter"):
+            for i, info in enumerate(self.layout.leaves):
                 if info.kind == "pos":
                     new_pools.append(None)
                     continue
                 x = jnp.squeeze(new_leaves[i], 1 + info.batch_axis)
                 if info.kind == "token":
                     new_pools.append(blocks.scatter_paged_tokens(
-                        pools[i], st[gidx[info.group]], x, info.token_axis,
-                        page))
+                        pools[i], st[self._gidx[info.group]], x,
+                        info.token_axis, self.page))
                 else:
                     new_pools.append(pools[i].at[ss].set(x))
-            return logits, tuple(new_pools)
+        return tuple(new_pools)
 
-        return engine._jit(fn, donate_cache_arg=1)
+    def _build_decode(self):
+        cfg, ctx = self.cfg, self.ctx
+
+        def serve_decode_wave(params, pools, gt, st, sg, ss, pos, toks):
+            cache = self._gather_slots(pools, gt, sg, pos)
+            logits, new_cache = jax.vmap(
+                lambda c, t: transformer.decode_step(params, cfg, ctx, c, t),
+                in_axes=(0, 0))(cache, toks)
+            return logits, self._scatter_slots(pools, new_cache, st, ss)
+
+        return engine._jit(serve_decode_wave, donate_cache_arg=1)
 
     def _build_decode_loads(self):
         """Loads-reporting twin of ``_build_decode`` for the expert-aware
@@ -281,48 +292,21 @@ class PagedCachePool:
         touched an offloaded expert and re-run it against the same input
         pools after restoring the weights."""
         cfg, ctx = self.cfg, self.ctx
-        infos, treedef = self.layout.leaves, self.layout.treedef
-        gidx, page = self._gidx, self.page
 
-        def fn(params, pools, gt, st, sg, ss, pos, toks):
-            leaves = []
-            for i, info in enumerate(infos):
-                if info.kind == "pos":
-                    leaves.append(pos)
-                elif info.kind == "token":
-                    x = blocks.gather_paged_tokens(
-                        pools[i], gt[gidx[info.group]], info.token_axis,
-                        info.group.length)
-                    leaves.append(jnp.expand_dims(x, 1 + info.batch_axis))
-                else:
-                    leaves.append(jnp.expand_dims(pools[i][sg],
-                                                  1 + info.batch_axis))
-            cache = jax.tree_util.tree_unflatten(treedef, leaves)
+        def serve_decode_wave_loads(params, pools, gt, st, sg, ss, pos, toks):
+            cache = self._gather_slots(pools, gt, sg, pos)
             logits, new_cache, load = jax.vmap(
                 lambda c, t: transformer.decode_step(params, cfg, ctx, c, t,
                                                      return_load=True),
                 in_axes=(0, 0))(cache, toks)
-            new_leaves = jax.tree_util.tree_flatten(new_cache)[0]
-            new_pools = []
-            for i, info in enumerate(infos):
-                if info.kind == "pos":
-                    new_pools.append(None)
-                    continue
-                x = jnp.squeeze(new_leaves[i], 1 + info.batch_axis)
-                if info.kind == "token":
-                    new_pools.append(blocks.scatter_paged_tokens(
-                        pools[i], st[gidx[info.group]], x, info.token_axis,
-                        page))
-                else:
-                    new_pools.append(pools[i].at[ss].set(x))
-            return logits, load, tuple(new_pools)
+            return logits, load, self._scatter_slots(pools, new_cache, st, ss)
 
-        return engine._jit(fn)
+        return engine._jit(serve_decode_wave_loads)
 
     def _build_install(self):
         infos, gidx, page = self.layout.leaves, self._gidx, self.page
 
-        def fn(pools, dense, tables, state_id):
+        def serve_install(pools, dense, tables, state_id):
             dl = jax.tree_util.tree_flatten(dense)[0]
             new_pools = []
             for i, info in enumerate(infos):
@@ -338,13 +322,13 @@ class PagedCachePool:
                     new_pools.append(pools[i].at[state_id].set(x))
             return tuple(new_pools)
 
-        return engine._jit(fn, donate_cache_arg=0)
+        return engine._jit(serve_install, donate_cache_arg=0)
 
     def _build_gather(self):
         infos, treedef = self.layout.leaves, self.layout.treedef
         gidx = self._gidx
 
-        def fn(pools, tables, state_vals, pos):
+        def serve_gather(pools, tables, state_vals, pos):
             leaves = []
             for i, info in enumerate(infos):
                 if info.kind == "pos":
@@ -359,12 +343,12 @@ class PagedCachePool:
                                                   info.batch_axis))
             return jax.tree_util.tree_unflatten(treedef, leaves)
 
-        return engine._jit(fn)
+        return engine._jit(serve_gather)
 
     def _build_restore(self):
         infos, gidx = self.layout.leaves, self._gidx
 
-        def fn(pools, rows, tables, state_id):
+        def serve_restore(pools, rows, tables, state_id):
             new_pools = []
             for i, info in enumerate(infos):
                 if info.kind == "pos":
@@ -376,19 +360,19 @@ class PagedCachePool:
                     new_pools.append(pools[i].at[state_id].set(rows[i]))
             return tuple(new_pools)
 
-        return engine._jit(fn, donate_cache_arg=0)
+        return engine._jit(serve_restore, donate_cache_arg=0)
 
     def _build_copy(self, group: Group):
         idxs = [i for i, info in enumerate(self.layout.leaves)
                 if info.kind == "token" and info.group == group]
 
-        def fn(pools, src, dst):
+        def serve_copy_page(pools, src, dst):
             out = list(pools)
             for i in idxs:
                 out[i] = pools[i].at[dst].set(pools[i][src])
             return tuple(out)
 
-        return engine._jit(fn, donate_cache_arg=0)
+        return engine._jit(serve_copy_page, donate_cache_arg=0)
 
     def _copy_page(self, group: Group, src: int, dst: int) -> None:
         self.pools = self._copy[group](self.pools, jnp.int32(src),
@@ -403,19 +387,26 @@ class PagedCachePool:
         for g in self.groups:
             self.ops.ensure_writable(rp, g, g.block_of(pos, self.page))
 
+    def _wave_inputs(self, slot_rps: list, pos: np.ndarray,
+                     toks: np.ndarray) -> tuple:
+        """A wave's device inputs after the params and pools: gather and
+        scatter tables, state ids, positions and tokens."""
+        with TraceAnnotation("serve.page_tables"):
+            return (self._tables(slot_rps, for_scatter=False),
+                    self._tables(slot_rps, for_scatter=True),
+                    self._state_ids(slot_rps, for_scatter=False),
+                    self._state_ids(slot_rps, for_scatter=True),
+                    jnp.asarray(pos.astype(np.int32)), jnp.asarray(toks))
+
     def decode_wave(self, params, slot_rps: list, pos: np.ndarray,
                     toks: np.ndarray):
         """One vmapped decode step over the slot map, paged: gather tables
         -> dense per-slot caches -> unchanged decode_step -> scatter back.
         ``slot_rps[s]`` is the RequestPages of the request in slot s (None =
         empty slot: reads the zero page, writes the scratch page)."""
-        gt = self._tables(slot_rps, for_scatter=False)
-        st = self._tables(slot_rps, for_scatter=True)
-        sg = self._state_ids(slot_rps, for_scatter=False)
-        ss = self._state_ids(slot_rps, for_scatter=True)
-        logits, self.pools = self._decode(
-            params, self.pools, gt, st, sg, ss,
-            jnp.asarray(pos.astype(np.int32)), jnp.asarray(toks))
+        inputs = self._wave_inputs(slot_rps, pos, toks)
+        with TraceAnnotation("serve.decode_dispatch"):
+            logits, self.pools = self._decode(params, self.pools, *inputs)
         return logits
 
     def decode_wave_loads(self, params, slot_rps: list, pos: np.ndarray,
@@ -429,13 +420,9 @@ class PagedCachePool:
         ``pool.pools = new_pools`` only after a residency-clean run."""
         if self._decode_loads is None:
             self._decode_loads = self._build_decode_loads()
-        gt = self._tables(slot_rps, for_scatter=False)
-        st = self._tables(slot_rps, for_scatter=True)
-        sg = self._state_ids(slot_rps, for_scatter=False)
-        ss = self._state_ids(slot_rps, for_scatter=True)
-        return self._decode_loads(
-            params, self.pools, gt, st, sg, ss,
-            jnp.asarray(pos.astype(np.int32)), jnp.asarray(toks))
+        inputs = self._wave_inputs(slot_rps, pos, toks)
+        with TraceAnnotation("serve.decode_dispatch"):
+            return self._decode_loads(params, self.pools, *inputs)
 
     def install(self, rp: RequestPages, dense, filled: int,
                 shared_len: int = 0) -> None:
@@ -479,13 +466,10 @@ class PagedCachePool:
         the layout's leaf order; None elsewhere) — what a prefix-trie node
         stores so an SSM/hybrid resume is bit-exact."""
         dl = jax.tree_util.tree_flatten(dense)[0]
-        out = []
-        for i, info in enumerate(self.layout.leaves):
-            if info.kind == "state":
-                out.append(np.asarray(jnp.squeeze(dl[i], info.batch_axis)))
-            else:
-                out.append(None)
-        return out
+        with TraceAnnotation("serve.fetch"):
+            return [np.asarray(jnp.squeeze(dl[i], info.batch_axis))
+                    if info.kind == "state" else None
+                    for i, info in enumerate(self.layout.leaves)]
 
     # -- preemption: spill to host / restore --------------------------------
 
@@ -497,17 +481,18 @@ class PagedCachePool:
         any reference is dropped — so an injected fault aborts the spill
         with the resident request and the allocator fully intact."""
         rows = []
-        for i, info in enumerate(self.layout.leaves):
-            if info.kind == "pos":
-                rows.append(None)
-            elif info.kind == "token":
-                t = [ZERO_PAGE if p is None else p
-                     for p in rp.tables[info.group]]
-                rows.append(np.asarray(self.pools[i][np.asarray(t)]))
-            else:
-                blk = rp.state_block
-                rows.append(np.asarray(self.pools[i][blk])
-                            if blk is not None else None)
+        with TraceAnnotation("serve.fetch"):
+            for i, info in enumerate(self.layout.leaves):
+                if info.kind == "pos":
+                    rows.append(None)
+                elif info.kind == "token":
+                    t = [ZERO_PAGE if p is None else p
+                         for p in rp.tables[info.group]]
+                    rows.append(np.asarray(self.pools[i][np.asarray(t)]))
+                else:
+                    blk = rp.state_block
+                    rows.append(np.asarray(self.pools[i][blk])
+                                if blk is not None else None)
         if fault_hook is not None:
             fault_hook("preempt_spill")
         saved = {

@@ -35,6 +35,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.core import memory_model as mm
@@ -165,7 +166,7 @@ class PagedScheduler(ContinuousBatchingScheduler):
 
     # -- admission: prefix reuse + escalation ladder -------------------------
 
-    def _admit(self) -> None:
+    def _admit(self, now: float) -> None:
         while self.queue and self.free_slots:
             req = self.queue[0]
             if req.spill is not None:
@@ -185,19 +186,23 @@ class PagedScheduler(ContinuousBatchingScheduler):
                     break
                 continue
             self.queue.popleft()
-            req.state = PREFILL
-            req.accepted = True
-            req.slot = self.free_slots.pop(0)
-            req.rp = self.pool.ops.new_request()
-            self._shared_len[req.rid] = matched
-            self._snapshots[req.rid] = {}
-            if matched:
-                self.trie.adopt(req.rp, nodes)
-                req.cache = self.pool.gather_dense(
-                    req.rp.tables, nodes[-1].snapshot, matched)
-                req.chunks_done = matched // self.scfg.prefill_chunk
-            self._prefilling = req
-            self.admission_order.append(req.rid)
+            with TraceAnnotation("serve.admit_request", rid=req.rid,
+                                 matched=matched):
+                req.state = PREFILL
+                req.accepted = True
+                if req.t_admit is None:
+                    req.t_admit = now
+                req.slot = self.free_slots.pop(0)
+                req.rp = self.pool.ops.new_request()
+                self._shared_len[req.rid] = matched
+                self._snapshots[req.rid] = {}
+                if matched:
+                    self.trie.adopt(req.rp, nodes)
+                    req.cache = self.pool.gather_dense(
+                        req.rp.tables, nodes[-1].snapshot, matched)
+                    req.chunks_done = matched // self.scfg.prefill_chunk
+                self._prefilling = req
+                self.admission_order.append(req.rid)
         self.max_occupancy = max(self.max_occupancy, self.occupancy())
         self.modeled_peak = max(self.modeled_peak, self.modeled_bytes())
 
@@ -284,15 +289,18 @@ class PagedScheduler(ContinuousBatchingScheduler):
         req = self._prefilling
         spans = chunk_spans(len(req.tokens), self.scfg.prefill_chunk)
         start, stop = spans[req.chunks_done]
-        seg = jnp.asarray(req.tokens[None, start:stop], jnp.int32)
-        logits, req.cache = self._prefill_compute(req, seg)
-        req.chunks_done += 1
-        self.prefill_chunks += 1
-        if (self.trie is not None and stop % self.align == 0
-                and stop <= self._registrable_len(len(req.tokens))):
-            # state at an aligned boundary: what a prefix-hit resume needs
-            self._snapshots[req.rid][stop] = self.pool.state_snapshot(
-                req.cache)
+        with TraceAnnotation("serve.prefill_chunk", rid=req.rid, start=start,
+                             stop=stop, length=len(req.tokens)):
+            seg = jnp.asarray(req.tokens[None, start:stop], jnp.int32)
+            logits, req.cache = self._prefill_compute(req, seg)
+            req.chunks_done += 1
+            self.prefill_chunks += 1
+            if (self.trie is not None and stop % self.align == 0
+                    and stop <= self._registrable_len(len(req.tokens))):
+                # state at an aligned boundary: what a prefix-hit resume
+                # needs
+                self._snapshots[req.rid][stop] = self.pool.state_snapshot(
+                    req.cache)
         if req.chunks_done == len(spans):
             self._install(req, logits, now)
 
@@ -307,22 +315,23 @@ class PagedScheduler(ContinuousBatchingScheduler):
 
     def _install(self, req: Request, logits, now: float) -> None:
         S = len(req.tokens)
-        try:
-            self.pool.install(req.rp, req.cache, S,
-                              shared_len=self._shared_len.get(req.rid, 0))
-        except Exception as exc:
-            if not (is_oom_error(exc) or isinstance(exc, PagesExhausted)):
-                raise
-            # physical pages ran out mid-install (or an injected CoW fault):
-            # requeue this request; nothing accepted is lost
-            self.faults += 1
-            self._requeue_prefilling(req)
-            return
-        if self.trie is not None:
-            upto = self._registrable_len(S) // self.align * self.align
-            if upto:
-                self.trie.register(req.tokens, upto, req.rp,
-                                   self._snapshots.get(req.rid, {}))
+        with TraceAnnotation("serve.install", rid=req.rid):
+            try:
+                self.pool.install(req.rp, req.cache, S,
+                                  shared_len=self._shared_len.get(req.rid, 0))
+            except Exception as exc:
+                if not (is_oom_error(exc) or isinstance(exc, PagesExhausted)):
+                    raise
+                # physical pages ran out mid-install (or an injected CoW
+                # fault): requeue this request; nothing accepted is lost
+                self.faults += 1
+                self._requeue_prefilling(req)
+                return
+            if self.trie is not None:
+                upto = self._registrable_len(S) // self.align * self.align
+                if upto:
+                    self.trie.register(req.tokens, upto, req.rp,
+                                       self._snapshots.get(req.rid, {}))
         req.cache = None
         req.pos = S
         req.state = ACTIVE
@@ -334,7 +343,7 @@ class PagedScheduler(ContinuousBatchingScheduler):
             req.next_token = req.pending_token
             req.pending_token = -1
         else:
-            self._append_token(req, np.asarray(logits[0, -1]), now)
+            self._first_token(req, logits, now)
 
     def _requeue_prefilling(self, req: Request) -> None:
         self.pool.release(req.rp)
@@ -363,23 +372,26 @@ class PagedScheduler(ContinuousBatchingScheduler):
             self._decode_wave_expert(now)
             return
         s = self.scfg
-        toks = np.zeros((s.max_slots, 1, 1), np.int32)
-        pos = np.zeros((s.max_slots,), np.int32)
         try:
-            for slot, req in self.active.items():
-                toks[slot, 0, 0] = req.next_token
-                pos[slot] = req.pos
-                # the write block must be exclusively owned before the wave
-                # (CoW fires here on ring wrap into a shared prefix page);
-                # runs before the generic wave fault point so an armed
-                # kind@step spec lands mid-CoW-fork when one is pending
-                self.pool.prepare_decode_write(req.rp, req.pos)
-            if self.injector is not None:
-                self.injector.maybe_fail_step(self.steps, "decode_wave")
-            slot_rps = [self.active[i].rp if i in self.active else None
-                        for i in range(s.max_slots)]
-            logits = np.asarray(
-                self.pool.decode_wave(self.params, slot_rps, pos, toks))
+            with TraceAnnotation("serve.decode_prepare"):
+                toks = np.zeros((s.max_slots, 1, 1), np.int32)
+                pos = np.zeros((s.max_slots,), np.int32)
+                for slot, req in self.active.items():
+                    toks[slot, 0, 0] = req.next_token
+                    pos[slot] = req.pos
+                    # the write block must be exclusively owned before the
+                    # wave (CoW fires here on ring wrap into a shared prefix
+                    # page); runs before the generic wave fault point so an
+                    # armed kind@step spec lands mid-CoW-fork when one is
+                    # pending
+                    self.pool.prepare_decode_write(req.rp, req.pos)
+                if self.injector is not None:
+                    self.injector.maybe_fail_step(self.steps, "decode_wave")
+                slot_rps = [self.active[i].rp if i in self.active else None
+                            for i in range(s.max_slots)]
+            logits = self.pool.decode_wave(self.params, slot_rps, pos, toks)
+            with TraceAnnotation("serve.fetch"):
+                logits = np.asarray(logits)
         except Exception as exc:
             if not (is_oom_error(exc) or isinstance(exc, PagesExhausted)):
                 raise
@@ -391,9 +403,10 @@ class PagedScheduler(ContinuousBatchingScheduler):
                 self._rebuild_pools()
             return
         self.decode_waves += 1
-        for slot, req in list(self.active.items()):
-            req.pos += 1
-            self._append_token(req, logits[slot, 0, -1], now)
+        with TraceAnnotation("serve.sample"):
+            for slot, req in list(self.active.items()):
+                req.pos += 1
+                self._append_token(req, logits[slot, 0, -1], now)
 
     # -- expert-aware wave hooks (docs/DESIGN.md §Residency) -----------------
 
@@ -416,19 +429,20 @@ class PagedScheduler(ContinuousBatchingScheduler):
         touches a non-member's pages; discarded demand re-runs reuse the
         unchanged input pools."""
         s = self.scfg
-        toks = np.zeros((s.max_slots, 1, 1), np.int32)
-        pos = np.zeros((s.max_slots,), np.int32)
-        for slot in members:
-            req = self.active[slot]
-            toks[slot, 0, 0] = req.next_token
-            pos[slot] = req.pos
-            # CoW before the wave, as in the FIFO path; ensure_writable is
-            # idempotent, so demand re-runs see the same owned block
-            self.pool.prepare_decode_write(req.rp, req.pos)
-        if self.injector is not None:
-            self.injector.maybe_fail_step(self.steps, "decode_wave")
-        slot_rps = [self.active[i].rp if mask[i] and i in self.active else None
-                    for i in range(s.max_slots)]
+        with TraceAnnotation("serve.decode_prepare"):
+            toks = np.zeros((s.max_slots, 1, 1), np.int32)
+            pos = np.zeros((s.max_slots,), np.int32)
+            for slot in members:
+                req = self.active[slot]
+                toks[slot, 0, 0] = req.next_token
+                pos[slot] = req.pos
+                # CoW before the wave, as in the FIFO path; ensure_writable
+                # is idempotent, so demand re-runs see the same owned block
+                self.pool.prepare_decode_write(req.rp, req.pos)
+            if self.injector is not None:
+                self.injector.maybe_fail_step(self.steps, "decode_wave")
+            slot_rps = [self.active[i].rp if mask[i] and i in self.active
+                        else None for i in range(s.max_slots)]
         out = {}
 
         def once():
@@ -437,12 +451,16 @@ class PagedScheduler(ContinuousBatchingScheduler):
             out["logits"], out["pools"] = logits, new_pools
             # non-member slots decoded garbage from the zero page: zero
             # their load rows so unions/telemetry only see members
-            out["load"] = np.asarray(load) * mask[:, None, None]
+            with TraceAnnotation("serve.fetch"):
+                load = np.asarray(load)
+            out["load"] = load * mask[:, None, None]
             return out["load"].sum(0) > 0, \
                 lambda: setattr(self.pool, "pools", out["pools"])
 
         self._demand_fixpoint(once)
-        return np.asarray(out["logits"]), out["load"]
+        with TraceAnnotation("serve.fetch"):
+            logits = np.asarray(out["logits"])
+        return logits, out["load"]
 
     def _rebuild_pools(self) -> None:
         if self.trie is not None:

@@ -32,6 +32,15 @@ wave's slots and *requeues* each accepted request at the head of the queue
 injected or real RESOURCE_EXHAUSTED never loses accepted work.  One request
 prefills at a time; its slot is reserved at admission so installation can
 never fail.
+
+Host spans (docs/DESIGN.md §Tracing): ``step`` and its phases are
+``jax.profiler.TraceAnnotation`` spans named ``serve.*`` (``serve.step``,
+``serve.admit``, ``serve.admit_request``, ``serve.prefill_chunk``,
+``serve.install``, ``serve.decode_prepare``, ``serve.page_tables``,
+``serve.decode_dispatch``, ``serve.fetch``, ``serve.sample``); spans of one
+request carry its ``rid``.
+They are recorded only while a profiler trace runs, and wrap the host syncs
+the step already makes (``serve.fetch``) without adding any.
 """
 
 from __future__ import annotations
@@ -45,6 +54,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import HardwareProfile, ModelConfig, device_profile
 from repro.core import memory_model as mm
@@ -77,6 +87,7 @@ class Request:
     cache: object = None                # private (B=1) cache while prefilling
     next_token: int = -1
     out: list = field(default_factory=list)
+    t_admit: Optional[float] = None     # first admission (s after start)
     t_first: Optional[float] = None     # first-token time (s after start)
     t_done: Optional[float] = None
     accepted: bool = False              # ever admitted — shed-exempt
@@ -162,9 +173,10 @@ class ContinuousBatchingScheduler:
             one)
         # donate the slot-pool cache off-CPU (engine._jit), same rationale
         # as the engine's decode step: waves rewrite every ring in place
-        self._decode = engine._jit(jax.vmap(
-            lambda p, c, t: transformer.decode_step(p, cfg, ctx, c, t),
-            in_axes=(None, 0, 0)), donate_cache_arg=1)
+        def serve_decode_slots(p, c, t):
+            return jax.vmap(
+                lambda c, t: transformer.decode_step(p, cfg, ctx, c, t))(c, t)
+        self._decode = engine._jit(serve_decode_slots, donate_cache_arg=1)
         # expert-aware decode + weight-residency tier (§Residency): any of
         # the three knobs engages the masked subset step, which also reports
         # per-slot routed loads (the telemetry feed)
@@ -341,18 +353,22 @@ class ContinuousBatchingScheduler:
             self.requeued += 1
             self.queue.appendleft(req)     # reverse slot order: slot 0 first
 
-    def _admit(self) -> None:
+    def _admit(self, now: float) -> None:
         """FIFO admission at step boundaries: a slot must be free, at most
         one request prefills at a time, and the serving memory model must
         accept one more resident cache (Eq. 3, serving form)."""
         while (self.queue and self.free_slots and self._prefilling is None
                and self._admissible(self.occupancy() + 1)):
             req = self.queue.popleft()
-            req.state = PREFILL
-            req.accepted = True
-            req.slot = self.free_slots.pop(0)
-            self._prefilling = req
-            self.admission_order.append(req.rid)
+            with TraceAnnotation("serve.admit_request", rid=req.rid,
+                                 matched=0):
+                req.state = PREFILL
+                req.accepted = True
+                if req.t_admit is None:
+                    req.t_admit = now
+                req.slot = self.free_slots.pop(0)
+                self._prefilling = req
+                self.admission_order.append(req.rid)
         # occupancy peaks at admission and only falls at evictions, so
         # measuring here (not at end-of-step, after same-step finishes
         # retired) is what makes the reported peak honest
@@ -365,10 +381,12 @@ class ContinuousBatchingScheduler:
         req = self._prefilling
         spans = chunk_spans(len(req.tokens), self.scfg.prefill_chunk)
         start, stop = spans[req.chunks_done]
-        seg = jnp.asarray(req.tokens[None, start:stop], jnp.int32)
-        logits, req.cache = self._prefill_compute(req, seg)
-        req.chunks_done += 1
-        self.prefill_chunks += 1
+        with TraceAnnotation("serve.prefill_chunk", rid=req.rid, start=start,
+                             stop=stop, length=len(req.tokens)):
+            seg = jnp.asarray(req.tokens[None, start:stop], jnp.int32)
+            logits, req.cache = self._prefill_compute(req, seg)
+            req.chunks_done += 1
+            self.prefill_chunks += 1
         if req.chunks_done == len(spans):
             self._install(req, logits, now)
 
@@ -385,8 +403,10 @@ class ContinuousBatchingScheduler:
                 and req.chunks_done == 0):
             # no telemetry yet: probe the prompt's routing on embeddings and
             # prefetch the predicted experts before the first chunk
-            counts = np.asarray(self._probe(
-                self.params, jnp.asarray(np.asarray(seg[0], np.int32))))
+            counts = self._probe(self.params,
+                                 jnp.asarray(np.asarray(seg[0], np.int32)))
+            with TraceAnnotation("serve.fetch"):
+                counts = np.asarray(counts)
             self.params = self.residency.prefetch(self.params, counts.sum(0) > 0)
         out = {}
 
@@ -395,7 +415,8 @@ class ContinuousBatchingScheduler:
                 self.params, self.cfg, self.ctx, req.cache, seg,
                 self.scfg.cache_len, return_load=True)
             out["logits"], out["cache"] = logits, cache
-            out["load"] = np.asarray(load)
+            with TraceAnnotation("serve.fetch"):
+                out["load"] = np.asarray(load)
             return out["load"] > 0, lambda: None
 
         self._demand_fixpoint(once)
@@ -408,9 +429,10 @@ class ContinuousBatchingScheduler:
     def _install(self, req: Request, logits, now: float) -> None:
         """Join at a step boundary: copy the private prefill cache into the
         reserved slot and sample the first token from the prefill logits."""
-        self.cache = jax.tree.map(
-            lambda full, one: full.at[req.slot].set(one),
-            self.cache, req.cache)
+        with TraceAnnotation("serve.install", rid=req.rid):
+            self.cache = jax.tree.map(
+                lambda full, one: full.at[req.slot].set(one),
+                self.cache, req.cache)
         req.cache = None
         req.state = ACTIVE
         if req.t_first is None:
@@ -423,7 +445,15 @@ class ContinuousBatchingScheduler:
             req.next_token = req.pending_token
             req.pending_token = -1
         else:
-            self._append_token(req, np.asarray(logits[0, -1]), now)
+            self._first_token(req, logits, now)
+
+    def _first_token(self, req: Request, logits, now: float) -> None:
+        """Sample a just-installed request's first token from its prefill
+        logits (a device array: fetching it is the host sync)."""
+        with TraceAnnotation("serve.fetch"):
+            logits_v = np.asarray(logits[0, -1])
+        with TraceAnnotation("serve.sample"):
+            self._append_token(req, logits_v, now)
 
     # -- decode -------------------------------------------------------------
 
@@ -477,7 +507,9 @@ class ContinuousBatchingScheduler:
             return sup
         if self._probe is not None:
             toks = np.asarray(req.tokens[-8:], np.int32)
-            counts = np.asarray(self._probe(self.params, jnp.asarray(toks)))
+            counts = self._probe(self.params, jnp.asarray(toks))
+            with TraceAnnotation("serve.fetch"):
+                counts = np.asarray(counts)
             return counts.sum(axis=0) > 0
         return None
 
@@ -572,24 +604,29 @@ class ContinuousBatchingScheduler:
         """Run one member wave to the fixpoint and commit its cache.
         Returns (logits, load) host arrays over ALL slots; non-member load
         rows are zero."""
-        toks = np.zeros((self.scfg.max_slots, 1, 1), np.int32)
-        for slot, req in self.active.items():
-            toks[slot, 0, 0] = req.next_token
-        if self.injector is not None:
-            self.injector.maybe_fail_step(self.steps, "decode_wave")
-        toks_j, mask_j = jnp.asarray(toks), jnp.asarray(mask)
+        with TraceAnnotation("serve.decode_prepare"):
+            toks = np.zeros((self.scfg.max_slots, 1, 1), np.int32)
+            for slot, req in self.active.items():
+                toks[slot, 0, 0] = req.next_token
+            if self.injector is not None:
+                self.injector.maybe_fail_step(self.steps, "decode_wave")
+            toks_j, mask_j = jnp.asarray(toks), jnp.asarray(mask)
         out = {}
 
         def once():
-            logits, new_cache, load = self._decode_masked(
-                self.params, self.cache, toks_j, mask_j)
+            with TraceAnnotation("serve.decode_dispatch"):
+                logits, new_cache, load = self._decode_masked(
+                    self.params, self.cache, toks_j, mask_j)
             out["logits"], out["cache"] = logits, new_cache
-            out["load"] = np.asarray(load)
+            with TraceAnnotation("serve.fetch"):
+                out["load"] = np.asarray(load)
             return out["load"].sum(0) > 0, \
                 lambda: setattr(self, "cache", out["cache"])
 
         self._demand_fixpoint(once)
-        return np.asarray(out["logits"]), out["load"]
+        with TraceAnnotation("serve.fetch"):
+            logits = np.asarray(out["logits"])
+        return logits, out["load"]
 
     def _decode_wave_expert(self, now: float) -> None:
         members = self._form_wave()
@@ -618,14 +655,15 @@ class ContinuousBatchingScheduler:
         self.wave_distinct_sum += int(
             np.count_nonzero(load.sum(axis=(0, 1)) > 0))
         member_set = set(members)
-        for slot, req in list(self.active.items()):
-            if slot not in member_set:
-                req.wave_wait += 1
-                continue
-            req.wave_wait = 0
-            self.telemetry.update(req.rid, load[slot])
-            self._advance_member(req)
-            self._append_token(req, logits[slot, 0, -1], now)
+        with TraceAnnotation("serve.sample"):
+            for slot, req in list(self.active.items()):
+                if slot not in member_set:
+                    req.wave_wait += 1
+                    continue
+                req.wave_wait = 0
+                self.telemetry.update(req.rid, load[slot])
+                self._advance_member(req)
+                self._append_token(req, logits[slot, 0, -1], now)
         if self.residency is not None:
             self.residency.note(load.sum(axis=0))
 
@@ -633,24 +671,29 @@ class ContinuousBatchingScheduler:
         if self._expert_aware:
             self._decode_wave_expert(now)
             return
-        toks = np.zeros((self.scfg.max_slots, 1, 1), np.int32)
-        for slot, req in self.active.items():
-            toks[slot, 0, 0] = req.next_token
         try:
             if self.injector is not None:
                 self.injector.maybe_fail_step(self.steps, "decode_wave")
-            logits, self.cache = self._decode(self.params, self.cache,
-                                              jnp.asarray(toks))
-            logits = np.asarray(logits)   # (slots, 1, 1, V): the host fetch
-        except Exception as exc:          # is where a real OOM surfaces
-            if not is_oom_error(exc):
+            with TraceAnnotation("serve.decode_prepare"):
+                toks = np.zeros((self.scfg.max_slots, 1, 1), np.int32)
+                for slot, req in self.active.items():
+                    toks[slot, 0, 0] = req.next_token
+                toks = jnp.asarray(toks)
+            with TraceAnnotation("serve.decode_dispatch"):
+                logits, self.cache = self._decode(self.params, self.cache,
+                                                  toks)
+            with TraceAnnotation("serve.fetch"):
+                logits = np.asarray(logits)   # (slots, 1, 1, V): the host
+        except Exception as exc:              # fetch is where a real OOM
+            if not is_oom_error(exc):         # surfaces
                 raise
             # the wave's donated slot pool may be torn — rebuild it
             self._wave_fault_reset(now)
             return
         self.decode_waves += 1
-        for slot, req in list(self.active.items()):
-            self._append_token(req, logits[slot, 0, -1], now)
+        with TraceAnnotation("serve.sample"):
+            for slot, req in list(self.active.items()):
+                self._append_token(req, logits[slot, 0, -1], now)
 
     # -- main loop ----------------------------------------------------------
 
@@ -658,18 +701,23 @@ class ContinuousBatchingScheduler:
         """One scheduler step: expire lapsed deadlines, admit, run one
         prefill chunk, run one decode wave.  Returns False when there was
         nothing to do."""
-        if self.injector is not None:
-            self.injector.maybe_stall(self.steps)      # stalled-prefill chaos
-        self._expire_deadlines(now)
-        self._admit()
-        busy = False
-        if self._prefilling is not None:
-            self._prefill_step(now)
-            busy = True
-        if self.active:
-            self._decode_wave(now)
-            busy = True
-        self.steps += 1
+        with TraceAnnotation("serve.step", step=self.steps):
+            if self.injector is not None:
+                self.injector.maybe_stall(self.steps)  # stalled-prefill chaos
+            with TraceAnnotation("serve.admit") as span:
+                admitted = len(self.admission_order)
+                self._expire_deadlines(now)
+                self._admit(now)
+                span.set_metadata(
+                    admitted=len(self.admission_order) - admitted)
+            busy = False
+            if self._prefilling is not None:
+                self._prefill_step(now)
+                busy = True
+            if self.active:
+                self._decode_wave(now)
+                busy = True
+            self.steps += 1
         return busy
 
     def run(self, requests: list[Request]) -> dict:

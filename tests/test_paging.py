@@ -8,6 +8,7 @@ preemption spill/restore and decode-time CoW in the loop.
 """
 
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -358,3 +359,143 @@ def test_paged_scheduler_requires_page_size():
     cfg, params = _model("llama3.2-3b")
     with pytest.raises(ValueError, match="page_size"):
         PagedScheduler(params, cfg, CTX, ServeConfig())
+
+
+# ---------------------------------------------------------------------------
+# tracing (docs/DESIGN.md §Tracing): host spans, step names, device scopes
+# ---------------------------------------------------------------------------
+
+def _recorded_events(trace_dir):
+    """(name, start, end, stats) of every host event of the newest trace."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+    path = max(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                         recursive=True), key=os.path.getmtime)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                out.append((ev.name, ev.start_ns,
+                            ev.start_ns + ev.duration_ns, dict(ev.stats)))
+    return out
+
+
+def test_paged_scheduler_records_serve_spans(tmp_path):
+    """A profiled run records the scheduler's phases as ``serve.*`` spans
+    (fetches nested in their step, one request's spans sharing its rid),
+    its steps as modules named ``jit_serve_*``, and the admission stamp on
+    the caller's clock."""
+    cfg, params = _model("mixtral-8x7b")
+    rng = np.random.default_rng(3)
+    stem = rng.integers(0, cfg.vocab_size, 16).astype(np.int32)
+    reqs = _trace(cfg, [(8, 4)] * 3, seed=3, prefix=stem)
+    sched = PagedScheduler(
+        params, cfg, CTX,
+        ServeConfig(max_slots=2, cache_len=48, prefill_chunk=8, page_size=8,
+                    prefix_cache=True), key=jax.random.PRNGKey(1))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for r in reqs:
+            sched.submit(r, 0.0)
+        t = 0.0
+        while len(sched.finished) < len(reqs):
+            t += 1.0
+            sched.step(t)
+    finally:
+        jax.profiler.stop_trace()
+    evs = _recorded_events(str(tmp_path))
+    spans = [e for e in evs if e[0].startswith("serve.")]
+    by = {}
+    for e in spans:
+        by.setdefault(e[0], []).append(e)
+    steps = by["serve.step"]
+    assert [st["step"] for _, _, _, st in steps] == list(range(len(steps)))
+    assert all(any(s <= a and b <= e for _, s, e, _ in steps)
+               for _, a, b, _ in by["serve.fetch"])
+    assert sum(st["admitted"] for *_, st in by["serve.admit"]) == len(reqs)
+    admitted = {st["rid"]: st["matched"] for *_, st in
+                by["serve.admit_request"]}
+    assert set(admitted) == {r.rid for r in reqs}
+    assert max(admitted.values()) >= 16        # later requests hit the stem
+    for *_, st in by["serve.prefill_chunk"]:
+        assert st["rid"] in admitted
+        assert 0 <= st["start"] < st["stop"] <= st["length"] == 24
+    assert {st["rid"] for *_, st in by["serve.install"]} == set(admitted)
+    for name in ("serve.decode_prepare", "serve.page_tables",
+                 "serve.decode_dispatch", "serve.sample"):
+        assert by[name]
+    # the pool's page tables are their own span, beside the scheduler's
+    # preparation and not inside it
+    assert not any(s <= a and b <= e for _, s, e, _ in
+                   by["serve.decode_prepare"]
+                   for _, a, b, _ in by["serve.page_tables"])
+    modules = {st["hlo_module"] for *_, st in evs if "hlo_module" in st}
+    assert {"jit_serve_decode_wave", "jit_serve_prefill_chunk",
+            "jit_serve_extend_chunk", "jit_serve_install",
+            "jit_serve_gather"} <= modules
+    assert "jit_fn" not in modules
+    for r in reqs:
+        assert r.t_admit is not None and r.t_admit <= r.t_first
+
+
+def test_pool_host_reads_are_fetch_spans(tmp_path):
+    """The pool's blocking device-to-host reads (a state snapshot, a spill)
+    each record one ``serve.fetch`` span."""
+    cfg, params = _model("jamba-1.5-large-398b")
+    toks = jnp.arange(16, dtype=jnp.int32)[None] % cfg.vocab_size
+    _, cache = engine.prefill_chunked(params, cfg, CTX, toks, 32, 8)
+    pool = PagedCachePool(params, cfg, CTX, 2, 32, 8)
+    rp = pool.ops.new_request()
+    pool.install(rp, cache, 16)
+    jax.block_until_ready(pool.pools)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        state = pool.state_snapshot(cache)
+        pool.spill(rp)
+    finally:
+        jax.profiler.stop_trace()
+    assert any(v is not None for v in state)
+    names = [e[0] for e in _recorded_events(str(tmp_path))
+             if e[0].startswith("serve.")]
+    assert names == ["serve.fetch", "serve.fetch"]
+
+
+def test_serving_steps_are_named_and_scoped():
+    """Every serving jit compiles to a module named after its function; the
+    paged decode wave's HLO carries the device scopes."""
+    cfg, params = _model("mixtral-8x7b")
+    scfg = ServeConfig(max_slots=2, cache_len=32, prefill_chunk=8,
+                       page_size=8)
+    sched = PagedScheduler(params, cfg, CTX, scfg)
+    pool = sched.pool
+    n = scfg.max_slots
+    inputs = pool._wave_inputs([None] * n, np.zeros((n,), np.int32),
+                               np.zeros((n, 1, 1), np.int32))
+    lowered = pool._decode.lower(params, pool.pools, *inputs)
+    hlo = lowered.compile().as_text()
+    assert "jit_serve_decode_wave" in lowered.as_text()
+    for scope in ("attention", "moe", "router", "experts", "paged_gather",
+                  "paged_scatter", "lm_head"):
+        # a scope under vmap reads ``vmap(<scope>)`` in an op_name
+        assert re.search(rf'op_name="[^"]*[/(]{scope}[/)]', hlo), scope
+    mono = ContinuousBatchingScheduler(params, cfg, CTX, scfg)
+    toks = jnp.zeros((n, 1, 1), jnp.int32)
+    assert "jit_serve_decode_slots" in mono._decode.lower(
+        params, mono.cache, toks).as_text()
+    names = {f.__name__ for f in (
+        engine.get_decode_step(cfg, CTX), engine.get_extend_step(cfg, CTX),
+        engine.get_prefill_fn(cfg, CTX, 32),
+        engine.get_decode_step_masked(cfg, CTX),
+        engine.get_extend_step_loads(cfg, CTX),
+        engine.get_prefill_fn_loads(cfg, CTX, 32),
+        engine.get_router_probe(cfg, CTX),
+        pool._decode, pool._build_decode_loads(), pool._install,
+        pool._gather, pool._restore, *pool._copy.values())}
+    assert names == {
+        "serve_decode_step", "serve_extend_chunk", "serve_prefill_chunk",
+        "serve_decode_masked", "serve_extend_chunk_loads",
+        "serve_prefill_chunk_loads", "serve_router_probe",
+        "serve_decode_wave", "serve_decode_wave_loads", "serve_install",
+        "serve_gather", "serve_restore", "serve_copy_page"}
