@@ -10,17 +10,23 @@ Strategies (docs/DESIGN.md §2):
   * ``dense``       — every expert on every token, masked combine.  O(E)
     compute; only used as a numerical oracle in tests.
 
+Decode (one token per row, ``moe_ffn_decode``) takes its own local path:
+each expert's weights are read once per wave, and only where a live token
+routes to it.
+
 All strategies share the same router and the same FCDA chunk loop, so Method
 1/2/3 comparisons (paper §5) are pure config switches.
 """
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.custom_batching import custom_vmap
 
 from repro.configs.base import MoEConfig
 from repro.core import dispatch as dsp
@@ -28,6 +34,7 @@ from repro.core.chunking import chunked_map
 from repro.core.ep import moe_ffn_ep
 from repro.core.router import init_router, route
 from repro.kernels.ops import expert_ffn
+from repro.kernels.ref import expert_ffn_ref
 
 
 @dataclass(frozen=True)
@@ -166,6 +173,118 @@ def _moe_ffn_rows(params: dict, x: jax.Array, cfg: MoEConfig,
         "drops": stats["drops"].sum(),
     }
     return y, stats
+
+
+# ---------------------------------------------------------------------------
+# decode path: one token per row, only the experts live tokens route to
+# (docs/DESIGN.md §Decode MoE)
+# ---------------------------------------------------------------------------
+
+EXPERT_WEIGHTS = ("w1", "w3", "w2")
+
+
+def _routed_experts(router: dict, experts: dict, layer, x: jax.Array,
+                    live: jax.Array, cfg: MoEConfig):
+    """The decode MoE over a wave's tokens: x (T, d), live (T,) bool.
+
+    Each expert's weights are read once, inside a ``lax.cond`` taken only
+    when some live token routes to it, for all T tokens at once.
+    ``experts`` holds the (E, ...) weights, or with ``layer`` the weights
+    stacked over scanned layers, (L, E, ...): the expert is indexed inside
+    the branch, where the slice fuses into the dot, so no layer's weights
+    are copied.  A dead token adds no expert and gets y = 0.
+
+    Returns (y (T, d), per-token aux (T,), per-token load (T, E) float32,
+    experts read () int32).
+    """
+    E = cfg.num_experts
+    with jax.named_scope("router"):
+        # each token routed on its own, as the row path routes each row
+        r = jax.vmap(lambda t: route(router, t[None], cfg))(x)
+    with jax.named_scope("dispatch"):
+        hit = r.expert_idx[:, 0, :, None] == jnp.arange(E)     # (T, K, E)
+        comb = jnp.where(hit, r.weights[:, 0, :, None], 0).sum(1)
+        comb = jnp.where(live[:, None], comb, 0)               # (T, E)
+        used = (hit.any(1) & live[:, None]).any(0)             # (E,)
+    at = () if layer is None else (layer,)
+
+    def read(e, y):
+        w1, w3, w2 = (experts[k][at + (e,)][None] for k in EXPERT_WEIGHTS)
+        return y + comb[:, e, None] * expert_ffn_ref(x[None], w1, w3, w2)[0]
+
+    y = jnp.zeros_like(x)
+    with jax.named_scope("experts"):
+        for e in range(E):
+            y = jax.lax.cond(used[e], functools.partial(read, e),
+                             lambda y: y, y)
+    return (y, r.aux_loss, r.load.astype(jnp.float32),
+            used.sum(dtype=jnp.int32))
+
+
+@functools.lru_cache(maxsize=None)
+def _decode_call(cfg: MoEConfig):
+    """``_routed_experts`` for ``cfg``, with a vmap rule that folds the
+    mapped axis (the serving slots) into the tokens.  Under plain vmap each
+    ``cond`` would become a select running every branch, once per slot."""
+    fn = functools.partial(_routed_experts, cfg=cfg)
+    call = custom_vmap(fn)
+
+    @call.def_vmap
+    def fold(n, in_batched, router, experts, layer, x, live):
+        if any(jax.tree.leaves(in_batched[:3])):
+            # weights differ per instance: nothing to share between them
+            axes = jax.tree.map(lambda b: 0 if b else None, in_batched)
+            return (jax.vmap(fn, in_axes=axes)(router, experts, layer, x,
+                                               live), (True,) * 4)
+        if not in_batched[3]:
+            x = jnp.broadcast_to(x, (n,) + x.shape)
+        if not in_batched[4]:
+            live = jnp.broadcast_to(live, (n,) + live.shape)
+        T = x.shape[1]
+        y, aux, load, reads = call(router, experts, layer,
+                                   x.reshape((n * T,) + x.shape[2:]),
+                                   live.reshape(n * T))
+        return ((y.reshape(x.shape), aux.reshape(n, T),
+                 load.reshape(n, T, -1), reads),
+                (True, True, True, False))
+
+    return call
+
+
+def moe_ffn_decode(params: dict, x: jax.Array, cfg: MoEConfig,
+                   ctx: DistContext, live: Optional[jax.Array] = None,
+                   layer=None):
+    """Decode MoE, x: (B, 1, d) -> (y, stats, experts read () int32).
+
+    On the local path (``tp_gspmd``) with one token per row, it reads each
+    expert's w1, w3 and w2 once, and only if a live token routes to it;
+    under vmap the mapped axis joins the tokens.  ``live`` (B,) bool marks
+    rows holding a request (default: all); a dead row adds no expert.
+    ``layer`` says the expert weights are stacked over scanned layers and
+    picks this one.  ``stats`` are the row path's numbers: ``load`` of every
+    row, dead ones included, ``drops`` 0 (one token cannot overflow an
+    expert) and ``aux_loss`` the mean per-row aux.  Other strategies and
+    shapes run ``moe_ffn``, and read all E experts.
+    """
+    B, S, _ = x.shape
+    if S != 1 or resolve_strategy(cfg, x.shape, ctx) != "tp_gspmd":
+        if layer is not None:
+            params = {**params,
+                      **{k: params[k][layer] for k in EXPERT_WEIGHTS}}
+        y, stats = moe_ffn(params, x, cfg, ctx)
+        return y, stats, jnp.int32(cfg.num_experts)
+    if live is None:
+        live = jnp.ones((B,), bool)
+    y, aux, load, reads = _decode_call(cfg)(
+        params["router"], {k: params[k] for k in EXPERT_WEIGHTS}, layer,
+        x[:, 0], live)
+    stats = {"aux_loss": aux.mean() / ctx.moe_chunks, "load": load.sum(0),
+             "drops": jnp.float32(0)}
+    y = y[:, None]
+    if "shared" in params:
+        with jax.named_scope("experts"):
+            y = y + _shared_expert(params, x)
+    return y, stats, reads
 
 
 # ---------------------------------------------------------------------------

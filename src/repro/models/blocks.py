@@ -21,7 +21,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from repro.configs.base import LayerSpec, ModelConfig
 from repro.core.chunking import ScheduleSpec
-from repro.core.moe import DistContext, init_moe, moe_ffn
+from repro.core.moe import DistContext, init_moe, moe_ffn, moe_ffn_decode
 from repro.models import ssm as ssm_mod
 from repro.models.attention import attention, decode_attention, extend_attention
 from repro.models.layers import (apply_mlp, apply_norm, apply_rope,
@@ -410,13 +410,14 @@ def _cross_qkv(p: dict, x: jax.Array, enc_out: jax.Array, cfg: ModelConfig):
 
 def apply_layer_decode(params: dict, x: jax.Array, cache, spec: LayerSpec,
                        cfg: ModelConfig, ctx: DistContext, pos: jax.Array, *,
-                       return_load: bool = False):
-    """Single-token decode.  cache: layer cache pytree.  Returns (x, cache).
-
-    ``return_load=True`` additionally returns this layer's (E,) routed-load
-    histogram (zeros for dense/none FFNs) — the per-step telemetry the
-    expert-aware serving path consumes (docs/DESIGN.md §Residency).  The
-    default path is unchanged."""
+                       live: Optional[jax.Array] = None, layer=None):
+    """Single-token decode.  cache: layer cache pytree.  Returns (x, cache,
+    load, reads): this layer's (E,) routed-load histogram, the per-step
+    telemetry the expert-aware serving path consumes (docs/DESIGN.md
+    §Residency), and the number of experts its MoE read (both zero for
+    dense/none FFNs).  ``live`` (B,) marks the rows holding a request and
+    ``layer`` says the expert weights are stacked over scanned layers
+    (``core.moe.moe_ffn_decode``, docs/DESIGN.md §Decode MoE)."""
     h = apply_norm(params["norm1"], x, cfg.norm)
     if spec.mixer == "attn":
         with jax.named_scope("attention"):
@@ -438,22 +439,19 @@ def apply_layer_decode(params: dict, x: jax.Array, cache, spec: LayerSpec,
         o = decode_attention(q, cache["cross_k"], cache["cross_v"],
                              Se * jnp.ones((B,), jnp.int32), spec.attn)
         x = x + o.reshape(B, 1, -1) @ params["cross"]["wo"]
-    load = None
+    load = zero_stats(cfg)["load"]
+    reads = jnp.int32(0)
     if spec.ffn != "none":
         h = apply_norm(params["norm2"], x, cfg.norm)
         if spec.ffn == "dense":
             h = apply_mlp(params["ffn"], h)
         else:
             with jax.named_scope("moe"):
-                h, st = moe_ffn(params["ffn"], h, cfg.moe, ctx)
-            load = st["load"].astype(jnp.float32)
+                h, st, reads = moe_ffn_decode(params["ffn"], h, cfg.moe, ctx,
+                                              live, layer)
+            load = st["load"]
         x = x + h
-    if return_load:
-        E = cfg.moe.num_experts if cfg.moe is not None else 1
-        if load is None:
-            load = jnp.zeros((E,), jnp.float32)
-        return x, cache, load
-    return x, cache
+    return x, cache, load, reads
 
 
 def apply_layer_extend(params: dict, x: jax.Array, cache, spec: LayerSpec,

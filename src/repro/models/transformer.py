@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import AttentionSpec, LayerSpec, ModelConfig
-from repro.core.moe import DistContext
+from repro.core.moe import EXPERT_WEIGHTS, DistContext
 from repro.models import blocks
 from repro.models.layers import apply_norm, init_norm
 
@@ -352,15 +352,18 @@ def init_cache(params: dict, cfg: ModelConfig, batch_size: int, seq_len: int,
 
 
 def decode_step(params: dict, cfg: ModelConfig, ctx: DistContext,
-                cache: dict, tokens: jax.Array, *, return_load: bool = False):
+                cache: dict, tokens: jax.Array, *, return_load: bool = False,
+                return_reads: bool = False, live: Optional[jax.Array] = None):
     """tokens: (B, 1) -> (logits (B, 1, V), new cache).  Position from cache.
 
     ``return_load=True`` appends the (L_moe, E) per-MoE-layer routed-load
     matrix to the return — same layer order as ``forward``'s
     ``load_per_layer`` (pre, scanned periods period-major, remainder) — the
     per-step telemetry source of the expert-aware serving path
-    (docs/DESIGN.md §Residency).  The default path is byte-identical to
-    before the flag existed."""
+    (docs/DESIGN.md §Residency).  ``return_reads=True`` appends the (L_moe,)
+    int32 count of experts each MoE layer read.  ``live`` (B,) bool marks
+    the rows that hold a request (default: all); a dead row adds no expert
+    to the MoE's reads (docs/DESIGN.md §Decode MoE)."""
     pos = cache["pos"]
     x = jnp.take(params["embed"], tokens, axis=0)
     if cfg.learned_pos:
@@ -369,71 +372,78 @@ def decode_step(params: dict, cfg: ModelConfig, ctx: DistContext,
     x = x.astype(params["embed"].dtype)
     pattern = cfg.pattern
     E = cfg.moe.num_experts if cfg.moe is not None else 1
-    layer_loads: list = []
+    loads: list = []          # per MoE layer, in layer order: (n, E)
+    reads: list = []          # (n,)
     new_cache: dict = {"pos": pos + 1}
+
+    def run(layer_params, x, layer_cache, spec, layer=None):
+        x, c, load, read = blocks.apply_layer_decode(
+            layer_params, x, layer_cache, spec, cfg, ctx, pos, live=live,
+            layer=layer)
+        if spec.ffn != "moe":
+            return x, c, jnp.zeros((0, E), jnp.float32), jnp.zeros((0,),
+                                                                   jnp.int32)
+        return x, c, load[None], read[None]
 
     new_pre = []
     for i, layer_params in enumerate(params.get("pre", [])):
-        out = blocks.apply_layer_decode(layer_params, x, cache["pre"][i],
-                                        cfg.prefix[i], cfg, ctx, pos,
-                                        return_load=return_load)
-        x, c = out[0], out[1]
+        x, c, load, read = run(layer_params, x, cache["pre"][i], cfg.prefix[i])
         new_pre.append(c)
-        if return_load and cfg.prefix[i].ffn == "moe":
-            layer_loads.append(out[2][None])
+        loads.append(load)
+        reads.append(read)
     new_cache["pre"] = new_pre
 
     if params["periods"] is not None:
-        def body(x, inp):
-            period_params, period_cache = inp
-            new_pc = []
-            loads_p = []
-            for i, spec in enumerate(pattern):
-                out = blocks.apply_layer_decode(period_params[i], x,
-                                                period_cache[i], spec, cfg,
-                                                ctx, pos,
-                                                return_load=return_load)
-                x = out[0]
-                new_pc.append(out[1])
-                if return_load and spec.ffn == "moe":
-                    loads_p.append(out[2])
-            if not return_load:
-                return x, new_pc
-            loads_p = (jnp.stack(loads_p) if loads_p
-                       else jnp.zeros((0, E), jnp.float32))
-            return x, (new_pc, loads_p)
+        periods = params["periods"]
+        # MoE expert weights stay stacked over the periods: the decode MoE
+        # indexes its layer inside the branch that reads an expert, so no
+        # period's expert weights are sliced out (a copy) by the scan
+        scanned = [
+            {**pp, "ffn": {k: v for k, v in pp["ffn"].items()
+                           if k not in EXPERT_WEIGHTS}}
+            if spec.ffn == "moe" else pp
+            for pp, spec in zip(periods, pattern)]
 
-        x, ys = jax.lax.scan(body, x, (params["periods"], cache["periods"]))
-        if return_load:
-            new_periods, loads_stack = ys
-            n_moe_pat = sum(1 for s in pattern if s.ffn == "moe")
-            if n_moe_pat:
-                layer_loads.append(
-                    loads_stack.reshape(cfg.num_periods * n_moe_pat, E))
-        else:
-            new_periods = ys
+        def body(x, inp):
+            period_params, period_cache, p = inp
+            new_pc, loads_p, reads_p = [], [], []
+            for i, spec in enumerate(pattern):
+                lp, layer = period_params[i], None
+                if spec.ffn == "moe":
+                    lp = {**lp, "ffn": {**lp["ffn"], **{
+                        k: periods[i]["ffn"][k] for k in EXPERT_WEIGHTS}}}
+                    layer = p
+                x, c, load, read = run(lp, x, period_cache[i], spec, layer)
+                new_pc.append(c)
+                loads_p.append(load)
+                reads_p.append(read)
+            return x, (new_pc, jnp.concatenate(loads_p),
+                       jnp.concatenate(reads_p))
+
+        x, (new_periods, loads_stack, reads_stack) = jax.lax.scan(
+            body, x, (scanned, cache["periods"],
+                      jnp.arange(cfg.num_periods)))
+        loads.append(loads_stack.reshape(-1, E))
+        reads.append(reads_stack.reshape(-1))
         new_cache["periods"] = new_periods
     else:
         new_cache["periods"] = None
 
     new_rem = []
     for i, layer_params in enumerate(params["rem"]):
-        spec = pattern[i % len(pattern)]
-        out = blocks.apply_layer_decode(layer_params, x, cache["rem"][i],
-                                        spec, cfg, ctx, pos,
-                                        return_load=return_load)
-        x, c = out[0], out[1]
+        x, c, load, read = run(layer_params, x, cache["rem"][i],
+                               pattern[i % len(pattern)])
         new_rem.append(c)
-        if return_load and spec.ffn == "moe":
-            layer_loads.append(out[2][None])
+        loads.append(load)
+        reads.append(read)
     new_cache["rem"] = new_rem
 
-    logits = unembed(params, cfg, x)
+    out = (unembed(params, cfg, x), new_cache)
     if return_load:
-        load_per_layer = (jnp.concatenate(layer_loads, axis=0) if layer_loads
-                          else jnp.zeros((0, E), jnp.float32))
-        return logits, new_cache, load_per_layer
-    return logits, new_cache
+        out += (jnp.concatenate(loads, axis=0),)
+    if return_reads:
+        out += (jnp.concatenate(reads, axis=0),)
+    return out
 
 
 def extend_step(params: dict, cfg: ModelConfig, ctx: DistContext,

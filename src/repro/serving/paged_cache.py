@@ -274,12 +274,14 @@ class PagedCachePool:
     def _build_decode(self):
         cfg, ctx = self.cfg, self.ctx
 
-        def serve_decode_wave(params, pools, gt, st, sg, ss, pos, toks):
+        def serve_decode_wave(params, pools, gt, st, sg, ss, pos, toks, live):
             cache = self._gather_slots(pools, gt, sg, pos)
-            logits, new_cache = jax.vmap(
-                lambda c, t: transformer.decode_step(params, cfg, ctx, c, t),
-                in_axes=(0, 0))(cache, toks)
-            return logits, self._scatter_slots(pools, new_cache, st, ss)
+            logits, new_cache, reads = jax.vmap(
+                lambda c, t, v: transformer.decode_step(
+                    params, cfg, ctx, c, t, return_reads=True, live=v))(
+                cache, toks, live)
+            return (logits, reads[0],
+                    self._scatter_slots(pools, new_cache, st, ss))
 
         return engine._jit(serve_decode_wave, donate_cache_arg=1)
 
@@ -293,13 +295,15 @@ class PagedCachePool:
         pools after restoring the weights."""
         cfg, ctx = self.cfg, self.ctx
 
-        def serve_decode_wave_loads(params, pools, gt, st, sg, ss, pos, toks):
+        def serve_decode_wave_loads(params, pools, gt, st, sg, ss, pos, toks,
+                                    live):
             cache = self._gather_slots(pools, gt, sg, pos)
-            logits, new_cache, load = jax.vmap(
-                lambda c, t: transformer.decode_step(params, cfg, ctx, c, t,
-                                                     return_load=True),
-                in_axes=(0, 0))(cache, toks)
-            return logits, load, self._scatter_slots(pools, new_cache, st, ss)
+            logits, new_cache, load, reads = jax.vmap(
+                lambda c, t, v: transformer.decode_step(
+                    params, cfg, ctx, c, t, return_load=True,
+                    return_reads=True, live=v))(cache, toks, live)
+            return (logits, load, reads[0],
+                    self._scatter_slots(pools, new_cache, st, ss))
 
         return engine._jit(serve_decode_wave_loads)
 
@@ -390,24 +394,30 @@ class PagedCachePool:
     def _wave_inputs(self, slot_rps: list, pos: np.ndarray,
                      toks: np.ndarray) -> tuple:
         """A wave's device inputs after the params and pools: gather and
-        scatter tables, state ids, positions and tokens."""
+        scatter tables, state ids, positions, tokens and the (slots, 1)
+        live mask (a slot holding a request)."""
         with TraceAnnotation("serve.page_tables"):
+            live = np.asarray([[rp is not None] for rp in slot_rps])
             return (self._tables(slot_rps, for_scatter=False),
                     self._tables(slot_rps, for_scatter=True),
                     self._state_ids(slot_rps, for_scatter=False),
                     self._state_ids(slot_rps, for_scatter=True),
-                    jnp.asarray(pos.astype(np.int32)), jnp.asarray(toks))
+                    jnp.asarray(pos.astype(np.int32)), jnp.asarray(toks),
+                    jnp.asarray(live))
 
     def decode_wave(self, params, slot_rps: list, pos: np.ndarray,
                     toks: np.ndarray):
         """One vmapped decode step over the slot map, paged: gather tables
         -> dense per-slot caches -> unchanged decode_step -> scatter back.
         ``slot_rps[s]`` is the RequestPages of the request in slot s (None =
-        empty slot: reads the zero page, writes the scratch page)."""
+        empty slot: reads the zero page, writes the scratch page, and adds
+        no expert to the wave).  Returns (logits, reads): reads is the
+        (L_moe,) int32 count of experts each MoE layer read."""
         inputs = self._wave_inputs(slot_rps, pos, toks)
         with TraceAnnotation("serve.decode_dispatch"):
-            logits, self.pools = self._decode(params, self.pools, *inputs)
-        return logits
+            logits, reads, self.pools = self._decode(params, self.pools,
+                                                     *inputs)
+        return logits, reads
 
     def decode_wave_loads(self, params, slot_rps: list, pos: np.ndarray,
                           toks: np.ndarray):
@@ -416,8 +426,9 @@ class PagedCachePool:
         reads the zero page and scatters to the scratch page, so committing
         the returned pools never perturbs a non-member's state — the paged
         form of the monolithic masked step's tree-select.  Returns
-        (logits, load (slots, L_moe, E), new_pools); the caller assigns
-        ``pool.pools = new_pools`` only after a residency-clean run."""
+        (logits, load (slots, L_moe, E), reads (L_moe,), new_pools); the
+        caller assigns ``pool.pools = new_pools`` only after a
+        residency-clean run."""
         if self._decode_loads is None:
             self._decode_loads = self._build_decode_loads()
         inputs = self._wave_inputs(slot_rps, pos, toks)

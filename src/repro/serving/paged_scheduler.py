@@ -75,6 +75,8 @@ class PagedScheduler(ContinuousBatchingScheduler):
                 lambda where: injector.maybe_fail_step(self.steps, where))
         self.preemptions = 0
         self.prefix_evictions = 0
+        # experts the decode waves read, of waves x MoE layers x E
+        self.decode_experts_read = self.decode_experts_total = 0
         self._snapshots: dict[int, dict] = {}   # rid -> {boundary: state}
         self._shared_len: dict[int, int] = {}   # rid -> adopted prefix len
 
@@ -88,6 +90,7 @@ class PagedScheduler(ContinuousBatchingScheduler):
         super().reset()
         self.preemptions = 0
         self.prefix_evictions = 0
+        self.decode_experts_read = self.decode_experts_total = 0
         self._snapshots.clear()
         self._shared_len.clear()
 
@@ -389,9 +392,10 @@ class PagedScheduler(ContinuousBatchingScheduler):
                     self.injector.maybe_fail_step(self.steps, "decode_wave")
                 slot_rps = [self.active[i].rp if i in self.active else None
                             for i in range(s.max_slots)]
-            logits = self.pool.decode_wave(self.params, slot_rps, pos, toks)
+            logits, reads = self.pool.decode_wave(self.params, slot_rps, pos,
+                                                  toks)
             with TraceAnnotation("serve.fetch"):
-                logits = np.asarray(logits)
+                logits, reads = jax.device_get((logits, reads))
         except Exception as exc:
             if not (is_oom_error(exc) or isinstance(exc, PagesExhausted)):
                 raise
@@ -403,10 +407,25 @@ class PagedScheduler(ContinuousBatchingScheduler):
                 self._rebuild_pools()
             return
         self.decode_waves += 1
+        self._count_reads(reads)
         with TraceAnnotation("serve.sample"):
             for slot, req in list(self.active.items()):
                 req.pos += 1
                 self._append_token(req, logits[slot, 0, -1], now)
+
+    def _count_reads(self, reads: np.ndarray) -> None:
+        """Add one wave's per-MoE-layer expert reads to the counters."""
+        self.decode_experts_read += int(reads.sum())
+        self.decode_experts_total += reads.size * (
+            self.cfg.moe.num_experts if self.cfg.moe is not None else 0)
+
+    @property
+    def decode_expert_read_share(self) -> Optional[float]:
+        """Experts the decode waves read over all they could have read
+        (docs/DESIGN.md §Decode MoE); None before a wave with an MoE."""
+        if not self.decode_experts_total:
+            return None
+        return self.decode_experts_read / self.decode_experts_total
 
     # -- expert-aware wave hooks (docs/DESIGN.md §Residency) -----------------
 
@@ -446,9 +465,9 @@ class PagedScheduler(ContinuousBatchingScheduler):
         out = {}
 
         def once():
-            logits, load, new_pools = self.pool.decode_wave_loads(
+            logits, load, reads, new_pools = self.pool.decode_wave_loads(
                 self.params, slot_rps, pos, toks)
-            out["logits"], out["pools"] = logits, new_pools
+            out["logits"], out["reads"], out["pools"] = logits, reads, new_pools
             # non-member slots decoded garbage from the zero page: zero
             # their load rows so unions/telemetry only see members
             with TraceAnnotation("serve.fetch"):
@@ -459,7 +478,8 @@ class PagedScheduler(ContinuousBatchingScheduler):
 
         self._demand_fixpoint(once)
         with TraceAnnotation("serve.fetch"):
-            logits = np.asarray(out["logits"])
+            logits, reads = jax.device_get((out["logits"], out["reads"]))
+        self._count_reads(reads)
         return logits, out["load"]
 
     def _rebuild_pools(self) -> None:
@@ -483,6 +503,9 @@ class PagedScheduler(ContinuousBatchingScheduler):
         m = super().metrics(elapsed)
         m["preemptions"] = self.preemptions
         m["prefix_evictions"] = self.prefix_evictions
+        m["decode_experts_read"] = self.decode_experts_read
+        m["decode_experts_total"] = self.decode_experts_total
+        m["decode_expert_read_share"] = self.decode_expert_read_share
         m["page_hwm_bytes"] = self.pool.alloc.hwm_bytes()
         m["page_allocated_bytes"] = self.pool.alloc.allocated_bytes()
         if self.trie is not None:

@@ -18,6 +18,7 @@ import pytest
 from repro.configs import registry
 from repro.configs.base import GPU_64G, HardwareProfile
 from repro.core import memory_model as mm
+from repro.core import moe
 from repro.core.moe import DistContext
 from repro.models import blocks, transformer
 from repro.serving import engine
@@ -260,13 +261,65 @@ def test_paged_decode_enc_dec_cross_attention():
         toks = np.asarray([[[tok]], [[0]]], np.int32)
         ref_logits, refc = step_fn(params, refc, jnp.asarray(toks))
         pool.prepare_decode_write(rp, 16 + step)
-        got = pool.decode_wave(params, [rp, None],
-                               np.asarray([16 + step, 0], np.int32), toks)
+        got, _ = pool.decode_wave(params, [rp, None],
+                                  np.asarray([16 + step, 0], np.int32), toks)
         np.testing.assert_array_equal(np.asarray(got[0]),
                                       np.asarray(ref_logits)[0])
         tok = int(np.argmax(np.asarray(ref_logits)[0, 0, -1]))
     pool.release(rp)
     pool.alloc.audit()
+
+
+def _rows_decode(params, x, cfg, ctx, live=None, layer=None):
+    """The decode MoE as the row path runs it: every expert, every slot."""
+    if layer is not None:
+        params = {**params,
+                  **{k: params[k][layer] for k in moe.EXPERT_WEIGHTS}}
+    y, stats = moe._moe_ffn_rows(params, x, cfg, ctx)
+    return y, stats, jnp.int32(cfg.num_experts)
+
+
+def test_paged_wave_with_empty_slot_matches_row_path(monkeypatch):
+    """Two requests in three slots, so every wave has an empty slot: the
+    live slots' logits match the row path's (docs/DESIGN.md §Decode MoE),
+    the empty slot adds no expert, and the scheduler's read share is the
+    experts read over waves x MoE layers x E."""
+    cfg, params = _model("mixtral-8x7b")
+    E, n_moe = cfg.moe.num_experts, transformer.num_moe_layers(cfg)
+
+    def run():
+        sched = PagedScheduler(
+            params, cfg, CTX,
+            ServeConfig(max_slots=3, cache_len=32, prefill_chunk=8,
+                        page_size=8), key=jax.random.PRNGKey(1))
+        seen, wave = [], sched.pool.decode_wave
+
+        def recorded(p, slot_rps, pos, toks):
+            logits, reads = wave(p, slot_rps, pos, toks)
+            live = [i for i, rp in enumerate(slot_rps) if rp is not None]
+            assert len(live) < len(slot_rps)
+            seen.append((np.asarray(logits)[live], np.asarray(reads)))
+            return logits, reads
+
+        sched.pool.decode_wave = recorded
+        sched.run(_trace(cfg, [(8, 6), (12, 4)], seed=5))
+        return sched, seen
+
+    new, seen_new = run()
+    monkeypatch.setattr(blocks, "moe_ffn_decode", _rows_decode)
+    old, seen_old = run()
+    _assert_parity(old, new)
+    assert len(seen_new) == len(seen_old) == new.decode_waves > 0
+    for (a, reads), (b, all_read) in zip(seen_new, seen_old):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+        assert reads.shape == (n_moe,) and (reads <= E).all()
+        np.testing.assert_array_equal(all_read, E)
+    assert new.decode_experts_total == new.decode_waves * n_moe * E
+    assert new.decode_experts_read == sum(r.sum() for _, r in seen_new)
+    assert 0 < new.decode_expert_read_share <= 1
+    assert old.decode_expert_read_share == 1.0
+    m = new.metrics(1.0)
+    assert m["decode_expert_read_share"] == new.decode_expert_read_share
 
 
 # ---------------------------------------------------------------------------
