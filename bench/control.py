@@ -52,24 +52,24 @@ def spread(g) -> dict:
 
 def study(quants: tuple, witness_eps):
     """An ``inspect`` hook for the serving driver; it fills ``readings``
-    with the spread of each set of gaps and ``raw`` with the gaps."""
+    with the spread of each set of gaps and ``raw`` with the gaps, read from
+    the reference module the driver hands it (the cell's adapter's)."""
     import numpy as np
-    from bench.models import mixtral
     readings, raw = {}, {}
 
     def read(name, parts):
         raw[name] = np.concatenate(parts)
         readings[name] = spread(raw[name])
 
-    def inspect(w, dm, prompts, outs):
-        ref = mixtral.served_logits(w, dm, prompts, outs)
-        read("program", mixtral.served_gaps(w, dm, prompts, outs, ref=ref))
+    def inspect(model, w, dm, prompts, outs):
+        ref = model.served_logits(w, dm, prompts, outs)
+        read("program", model.served_gaps(w, dm, prompts, outs, ref=ref))
         if witness_eps is not None:
             dw = dataclasses.replace(dm, eps=witness_eps)
             read(f"program_eps_{witness_eps:g}",
-                 mixtral.served_gaps(w, dw, prompts, outs))
+                 model.served_gaps(w, dw, prompts, outs))
         for q in quants:
-            read(f"control_{q}", mixtral.served_gaps(
+            read(f"control_{q}", model.served_gaps(
                 w, dm, prompts, outs, quant=q, ref=ref))
 
     return inspect, readings, raw

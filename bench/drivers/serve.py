@@ -3,7 +3,10 @@
 with an open-loop schedule from ``bench/traffic.py``, stamps every output
 token on the host clock when the step that produced it returns (the host
 fetch of the logits inside ``step`` is the device sync), and then checks
-what the timed path served against the plain float32 reference.
+what the timed path served against the plain float32 reference.  What is the
+architecture's (the program's model for the configuration file, the
+reference and its view of the weights) comes from the configuration's
+adapter, ``bench/arch/<model_type>.py``.
 
 Set-up: weights from the seed (one jitted call, bfloat16), the scheduler
 and its compiled steps, then the mix's warm-up requests, which compile
@@ -13,79 +16,32 @@ prefix trie.  The lead-in's requests then fill the server, and the window
 opens.  After it closes the loop keeps stepping, without new arrivals,
 until every request due in the window has its first token, for at most the
 mix's ``drain_s``.
+
+A traced run also keeps the scheduler's own counters (``metrics()``) where
+the trace starts and where the window closes, so that they count the waves
+the trace times, and, after the window, the scope maps of the decode wave
+and the prefill and extend chunks (``serving_scope_maps``), which
+``bench/trace.py`` reads the trace's ops by.
 """
 
 from __future__ import annotations
 
-import dataclasses
 import gc
 import time
 from dataclasses import dataclass, field
 
 import numpy as np
 
+from bench import arch as arch_mod
+from bench import trace as trace_mod
 from bench import traffic as traffic_mod
 from bench import weights as weights_mod
-from bench.models import mixtral
 
 TRACE_SECONDS = 10.0          # length of the traced part of a --trace 1 run
 #: the number ``correct`` compares (its limit and tolerance are in
 #: ``bench/limits/<cell>.json``)
 SHARE = "tokens_off_best_share"
 IDLE_SLEEP_S = 0.002
-
-
-def program_config(c: dict):
-    """The program's ``ModelConfig`` for configuration file ``c``: the
-    program's own architecture entry with every size taken from the file."""
-    from repro.configs import get_config
-    base = get_config(c["bench"]["program_arch"])
-    if base.norm != "rmsnorm" or base.tie_embeddings != c[
-            "tie_word_embeddings"]:
-        raise ValueError(f"{base.name}: norm or embedding tie differs from "
-                         "the configuration file")
-    (spec,) = base.pattern
-    attn = dataclasses.replace(spec.attn, window=c.get("sliding_window") or 0,
-                               kind="window" if c.get("sliding_window")
-                               else "full")
-    moe = dataclasses.replace(base.moe, num_experts=c["num_local_experts"],
-                              top_k=c["num_experts_per_tok"],
-                              d_ff_expert=c["intermediate_size"])
-    return dataclasses.replace(
-        base, num_layers=c["num_hidden_layers"], d_model=c["hidden_size"],
-        num_heads=c["num_attention_heads"],
-        num_kv_heads=c["num_key_value_heads"],
-        head_dim=c.get("head_dim") or 0, d_ff=c["intermediate_size"],
-        vocab_size=c["vocab_size"], rope_theta=float(c["rope_theta"]),
-        pattern=(dataclasses.replace(spec, attn=attn),), moe=moe,
-        dtype=c["torch_dtype"])
-
-
-def reference_weights(params: dict) -> mixtral.Weights:
-    """The reference's view of the benchmark-made weights (the program's
-    tree layout: scanned periods stacked on a leading axis, then the
-    unrolled remainder)."""
-    layers = []
-
-    def add(p, at):
-        pick = (lambda a: a[at]) if at is not None else (lambda a: a)
-        layers.append(mixtral.Layer(
-            norm1=pick(p["norm1"]["scale"]), wq=pick(p["mixer"]["wq"]),
-            wk=pick(p["mixer"]["wk"]), wv=pick(p["mixer"]["wv"]),
-            wo=pick(p["mixer"]["wo"]), norm2=pick(p["norm2"]["scale"]),
-            router=pick(p["ffn"]["router"]["w"]), w1=p["ffn"]["w1"],
-            w3=p["ffn"]["w3"], w2=p["ffn"]["w2"],
-            at=() if at is None else (at,)))
-
-    if params["periods"] is not None:
-        (stack,) = params["periods"]
-        for i in range(stack["norm1"]["scale"].shape[0]):
-            add(stack, i)
-    for p in params["rem"]:
-        add(p, None)
-    return mixtral.Weights(embed=params["embed"], head=params["head"],
-                           final_norm=params["final_norm"]["scale"],
-                           layers=layers)
 
 
 @dataclass
@@ -111,6 +67,11 @@ class ServeRun:
     requeues: int = 0
     memory_peak_bytes: int = 0
     trace: object = None                   # bench.trace.Summary
+    counters: dict = field(default_factory=dict)   # "open"/"close" -> the
+    #                          scheduler's metrics() at the trace's ends
+    admitted: dict = field(default_factory=dict)   # rid -> absolute admit
+    scope_maps: dict = field(default_factory=dict)  # module -> hlo_scopes,
+    #                                        traced runs
     attempted: int = 0
     failed: int = 0
     checks: dict = field(default_factory=dict)     # name -> (value, limit)
@@ -150,8 +111,9 @@ class Server:
         from repro.serving.paged_scheduler import PagedScheduler
         from repro.serving.scheduler import ServeConfig
         self.config = c
+        self.arch = arch_mod.adapter(c)
         self.sv = c["bench"]["serving"]
-        cfg = program_config(c)
+        cfg = self.arch.program_config(c)
         shapes = jax.eval_shape(lambda k: transformer.init_params(k, cfg),
                                 jax.random.PRNGKey(0))
         self.params = weights_mod.make(shapes, seed,
@@ -217,6 +179,7 @@ class Server:
                 if counter is not None:
                     counter.on = True
             if trace_at is not None and now >= trace_at:
+                rec.counters["open"] = sched.metrics(now - w0)
                 jax.profiler.start_trace(trace_dir)
                 window_ann = jax.profiler.TraceAnnotation("bench.window")
                 window_ann.__enter__()
@@ -224,6 +187,8 @@ class Server:
             if now >= w1:
                 if counter is not None:
                     counter.on = False
+                if trace_dir and "close" not in rec.counters:
+                    rec.counters["close"] = sched.metrics(now - w0)
                 if window_ann is not None:
                     window_ann.__exit__(None, None, None)
                     window_ann = None
@@ -273,6 +238,8 @@ class Server:
         rec.drain_end = time.perf_counter()
         if trace_dir:
             jax.profiler.stop_trace()
+        rec.admitted = {rid: self.t0 + r.t_admit for rid, r in reqs.items()
+                        if r.t_admit is not None}
         rec.faults = sched.faults - faults0
         rec.requeues = sched.requeued - requeues0
         rec.attempted = len(rec.in_window)
@@ -283,9 +250,9 @@ class Server:
 def run(cell, *, seed: int, seconds: float, trace: bool, t_start: float,
         out_dir: str, devices, inspect=None) -> ServeRun:
     """One run of ``cell``.  ``inspect``, when given, is called after the
-    comparison as ``inspect(weights, dims, prompts, outs)`` with the
-    reference's weights and the sampled requests (``bench/control.py``
-    reads the control there)."""
+    comparison as ``inspect(ref, weights, dims, prompts, outs)`` with the
+    reference (the adapter's ``serve_reference``), its weights and the
+    sampled requests (``bench/control.py`` reads the control there)."""
     from repro.serving.scheduler import FINISHED
     c, mix = cell.config, cell.traffic
     traffic_mod.check_fits(mix, c["bench"]["serving"]["cache_len"])
@@ -303,21 +270,24 @@ def run(cell, *, seed: int, seconds: float, trace: bool, t_start: float,
                         counter=counter)
     rec.compiles_in_window = counter.count
     rec.memory_peak_bytes = _memory_peak(devices)
+    if trace:
+        rec.scope_maps = serving_scope_maps(server)
 
     # -- correctness: free the program's state, then the reference ----------
     finished = [req for req in reqs.values() if req.state == FINISHED]
-    params = server.params
+    params, arch = server.params, server.arch
     del server, reqs
     gc.collect()
     t_ref = time.perf_counter()
     sample = _sample(finished, c["bench"]["check"]["requests"], seed)
     prompts = [np.asarray(r.prompt) for r in sample]
     outs = [list(r.out) for r in sample]
-    w, dm = reference_weights(params), mixtral.Dims.from_config(c)
-    rec.checks, rec.correct = _check(c, cell.limits, w, dm, prompts, outs)
+    w, dm = arch.reference_weights(params), arch.dims(c)
+    rec.checks, rec.correct = _check(c, cell.limits, arch.serve_reference,
+                                     w, dm, prompts, outs)
     rec.reference_s = time.perf_counter() - t_ref
     if inspect is not None:
-        inspect(w, dm, prompts, outs)
+        inspect(arch.serve_reference, w, dm, prompts, outs)
     del params, w
     gc.collect()
     return rec
@@ -337,11 +307,40 @@ def _sample(finished: list, n: int, seed: int) -> list:
     return [longest] + picked
 
 
-def _check(c: dict, limits: dict, w, dm, prompts: list, outs: list):
+def serving_scope_maps(server) -> dict:
+    """{module: ``bench.trace.hlo_scopes``} of the decode wave and the
+    prefill and extend chunks of a built server's scheduler, compiled again
+    at the shapes the cell runs (the persistent compile cache answers).
+    The trace's op events carry no ``op_name``; this names their scopes."""
+    import jax
+    import jax.numpy as jnp
+    from repro.serving import engine
+    sched, sv = server.sched, server.sv
+    pool, n = sched.pool, sv["max_slots"]
+    inputs = pool._wave_inputs([None] * n, np.zeros((n,), np.int32),
+                               np.zeros((n, 1, 1), np.int32))
+    tok = jax.ShapeDtypeStruct((1, sv["prefill_chunk"]), jnp.int32)
+    prefill = engine.get_prefill_fn(sched.cfg, sched.ctx, sv["cache_len"])
+    extend = engine.get_extend_step(sched.cfg, sched.ctx)
+    cache = jax.eval_shape(prefill, sched.params, {"tokens": tok})[1]
+    lowered = {
+        "jit_serve_decode_wave": pool._decode.lower(
+            sched.params, pool.pools, *inputs),
+        "jit_serve_prefill_chunk": prefill.lower(sched.params,
+                                                 {"tokens": tok}),
+        "jit_serve_extend_chunk": extend.lower(sched.params, cache, tok),
+    }
+    scopes = trace_mod.SCOPES + tuple(getattr(server.arch, "SCOPES", ()))
+    return {m: trace_mod.hlo_scopes(low.compile().as_text(), scopes)
+            for m, low in lowered.items()}
+
+
+def _check(c: dict, limits: dict, ref, w, dm, prompts: list, outs: list):
     """Over the sampled requests: the share of served tokens whose logit in
-    the reference lies more than the limits file's ``tolerance`` below the
-    reference's best at that position, against its limit; and that the
-    sample holds enough served tokens.
+    the reference ``ref`` (the adapter's ``serve_reference``) lies more than
+    the limits file's ``tolerance`` below the reference's best at that
+    position, against its limit; and that the sample holds enough served
+    tokens.
 
     Not the widest gap, the mean or a percentile: random weights give flat
     logits, so bfloat16 rounding decides some near-ties the other way (a
@@ -359,7 +358,7 @@ def _check(c: dict, limits: dict, w, dm, prompts: list, outs: list):
     if not prompts:
         return {SHARE: (float("nan"), limit)}, False
     need = int(c["bench"]["check"]["min_tokens"])
-    gaps = np.concatenate(mixtral.served_gaps(w, dm, prompts, outs))
+    gaps = np.concatenate(ref.served_gaps(w, dm, prompts, outs))
     share = float(np.mean(gaps > tol)) if gaps.size else float("nan")
     checks = {SHARE: (share, limit),
               "served_tokens_compared": (int(gaps.size), need)}

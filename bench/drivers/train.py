@@ -17,10 +17,15 @@ The window opens at a step boundary, holds every step that starts before
 profiler on, the trace covers the steps that start in the window's last
 ``TRACE_SECONDS``.
 
+What is the architecture's (the program's model for the configuration
+file, the plain reference, its view and placement of the weights and its
+arguments) comes from the configuration's adapter,
+``bench/arch/<model_type>.py``.
+
 The check (limits in ``bench/limits/<cell>.json``) compares, after the
-program's state is freed, the first three steps with the reference's
-(``bench/models/mixtral_train.py``) on the same bench-made weights and
-batches, at the chunk count MACT used in each step:
+program's state is freed, the first three steps with the reference's (the
+adapter's ``train_reference``) on the same bench-made weights and batches,
+at the chunk count MACT used in each step:
 
 * ``loss_rel_err``: the largest relative gap of the logged loss of steps 1-3;
 * ``grad_norm_gap``: the first gradient as the optimizer took it (clipped),
@@ -43,7 +48,6 @@ batches, at the chunk count MACT used in each step:
 
 from __future__ import annotations
 
-import dataclasses
 import gc
 import math
 import time
@@ -52,10 +56,10 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from bench import arch as arch_mod
 from bench import train_data
 from bench import weights as weights_mod
 from bench.drivers import serve
-from bench.models import mixtral, mixtral_train
 
 TRACE_SECONDS = 8.0
 #: each number compared -> the per-leaf (per-step) gaps it reduces, and how
@@ -64,27 +68,6 @@ CHECKS = {"loss_rel_err": ("loss_rel_err", np.max),
           "change_norm_gap": ("change_norm_gap", np.max),
           "grad_diff": ("grad_diff", np.max),
           "grad_diff_median": ("grad_diff", np.median)}
-#: a program layer's leaf -> the reference layer's key
-LAYER_LEAVES = {("norm1", "scale"): "norm1", ("mixer", "wq"): "wq",
-                ("mixer", "wk"): "wk", ("mixer", "wv"): "wv",
-                ("mixer", "wo"): "wo", ("norm2", "scale"): "norm2",
-                ("ffn", "router", "w"): "router", ("ffn", "w1"): "w1",
-                ("ffn", "w3"): "w3", ("ffn", "w2"): "w2"}
-
-
-def program_config(c: dict):
-    """The program's ``ModelConfig`` for training configuration ``c``: the
-    serving cells' mapping of the file's sizes, with float32 master weights,
-    the file's remat policy, aux coefficient and expert strategy."""
-    tr = c["bench"]["training"]
-    cfg = serve.program_config(c)
-    moe = dataclasses.replace(cfg.moe,
-                              router_aux_coef=float(c["router_aux_loss_coef"]),
-                              strategy=tr["moe_strategy"])
-    return dataclasses.replace(cfg, dtype=tr["master_dtype"],
-                               remat_policy=tr["remat_policy"], moe=moe)
-
-
 @dataclass
 class TrainRun:
     """What one run recorded; the metric readers read it."""
@@ -117,52 +100,6 @@ def _path(path) -> str:
     return weights_mod._path_str(path)
 
 
-def reference_leaves(prog_leaf: str, n_periods: int) -> list:
-    """The reference's ``mixtral_train.flat`` names of a program leaf: a
-    leaf stacked over scanned periods holds one per period; a leaf the
-    reference has no use for (the router's bias, unused without loss-free
-    balancing) has none."""
-    parts = prog_leaf.split("/")
-    if parts[0] in ("embed", "head"):
-        return [parts[0]]
-    if parts[0] == "final_norm":
-        return ["final_norm"]
-    key = LAYER_LEAVES.get(tuple(parts[2:]))
-    if key is None:
-        return []
-    if parts[0] == "periods":
-        return [f"layers/{i}/{key}" for i in range(n_periods)]
-    if parts[0] == "rem":
-        return [f"layers/{n_periods + int(parts[1])}/{key}"]
-    raise ValueError(f"no reference leaf for {prog_leaf!r}")
-
-
-def reference_tree(params: dict) -> dict:
-    """The reference's weights (``mixtral_train`` layout) from the program's
-    tree: scanned periods unstacked, then the unrolled remainder."""
-    layers = []
-
-    def add(p, at):
-        pick = (lambda a: a[at]) if at is not None else (lambda a: a)
-        layers.append({key: pick(_get(p, parts))
-                       for parts, key in LAYER_LEAVES.items()})
-
-    if params["periods"] is not None:
-        (stack,) = params["periods"]
-        for i in range(stack["norm1"]["scale"].shape[0]):
-            add(stack, i)
-    for p in params["rem"]:
-        add(p, None)
-    return {"embed": params["embed"], "head": params["head"],
-            "final_norm": params["final_norm"]["scale"], "layers": layers}
-
-
-def _get(tree, parts):
-    for p in parts:
-        tree = tree[p]
-    return tree
-
-
 class Job:
     """The program under test, built once from the configuration, the mix
     and the seed: the trainer with its compiled steps, its state and the
@@ -178,8 +115,9 @@ class Job:
         from repro.training.step import TrainState, train_state_shardings
         from repro.training.trainer import Trainer
         self.c, self.seed = c, seed
+        self.arch = arch_mod.adapter(c)
         tr = c["bench"]["training"]
-        self.cfg = cfg = program_config(c)
+        self.cfg = cfg = self.arch.train_program_config(c)
         self.mesh = make_mesh((1, len(devices)), ("data", "model"))
         self.data = train_data.TrainBatches(mix, seed, c["vocab_size"])
         self.trainer = Trainer(
@@ -213,7 +151,7 @@ class Job:
     def leaf_norms(self, tree) -> dict:
         import jax
         flat, _ = jax.tree_util.tree_flatten_with_path(
-            mixtral_train.leaf_norms(tree))
+            self.arch.train_reference.leaf_norms(tree))
         return {_path(p): float(v) for p, v in flat}
 
     def change_norms(self) -> dict:
@@ -248,8 +186,8 @@ class Job:
                                 self.leaf_norms(self.state.opt.mu).items()}
                 out["grad1_full"] = {
                     k: np.asarray(v) / (1.0 - b1) for k, v in
-                    mixtral_train.flat(reference_tree(jax.device_get(
-                        self.state.opt.mu))).items()}
+                    self.arch.train_reference.flat(self.arch.reference_tree(
+                        jax.device_get(self.state.opt.mu))).items()}
                 out["copy_s"] = time.perf_counter() - t
             if i + 1 == check_steps:
                 out["change"] = self.change_norms()
@@ -296,10 +234,11 @@ class Job:
 def run(cell, *, seed: int, seconds: float, trace: bool, t_start: float,
         out_dir: str, devices, inspect=None) -> TrainRun:
     """One run of ``cell``.  ``inspect``, when given, is called after the
-    comparison as ``inspect(weights, batches, args, program, reference)``:
-    the reference's placed weights, the checked batches, the keyword
-    arguments of ``mixtral_train.train_steps``, the program's readings and
-    the reference's (``bench/train_control.py`` reads the control there)."""
+    comparison as ``inspect(model, dims, weights, batches, args, program,
+    reference)``: the reference module (the adapter's ``train_reference``)
+    and its sizes, its placed weights, the checked batches, the keyword
+    arguments of its ``train_steps``, the program's readings and the
+    reference's (``bench/train_control.py`` reads the control there)."""
     c, mix = cell.config, cell.traffic
     tr = c["bench"]["training"]
     rec = TrainRun(config=c, seconds=float(seconds), chips=len(devices),
@@ -328,7 +267,8 @@ def run(cell, *, seed: int, seconds: float, trace: bool, t_start: float,
     # -- correctness: free the program's state, then the reference ----------
     n_periods = (0 if job.shapes["periods"] is None else
                  job.shapes["periods"][0]["norm1"]["scale"].shape[0])
-    prog["refs"] = {k: reference_leaves(k, n_periods)
+    arch = job.arch
+    prog["refs"] = {k: arch.reference_leaves(k, n_periods)
                     for k in prog["grad1"]}
     data, make_params = job.data, job.make_params
     alive = weakref.ref(job)
@@ -338,52 +278,20 @@ def run(cell, *, seed: int, seconds: float, trace: bool, t_start: float,
         raise RuntimeError("the program's job outlives the window: its state "
                            "would hold the chips' memory the reference needs")
     t_ref = time.perf_counter()
-    weights = _reference_weights(make_params, devices)
+    weights = arch.train_reference_weights(make_params, devices)
     steps = len(prog["losses"])
     batches = [(b["tokens"], b["labels"]) for b in
                (data.batch_at(i) for i in range(steps))]
-    args = reference_args(c, len(devices), prog["chunks"])
-    ref = mixtral_train.train_steps(weights, batches,
-                                    mixtral.Dims.from_config(c), **args,
-                                    keep_grad1=True)
+    args = arch.reference_args(c, len(devices), prog["chunks"])
+    model, dm = arch.train_reference, arch.dims(c)
+    ref = model.train_steps(weights, batches, dm, **args, keep_grad1=True)
     rec.checks, rec.correct = compare(prog, ref, cell.limits)
     rec.reference_s = time.perf_counter() - t_ref
     if inspect is not None:
-        inspect(weights, batches, args, prog, ref)
+        inspect(model, dm, weights, batches, args, prog, ref)
     del weights
     gc.collect()
     return rec
-
-
-def reference_args(c: dict, devices: int, chunks: list) -> dict:
-    """Keyword arguments of ``mixtral_train.train_steps`` for the
-    configuration: the EP split, the chunk count of each step, the aux
-    coefficient and AdamW's hyperparameters."""
-    h = c["bench"]["training"]["adamw"]
-    lr = float(c["bench"]["training"]["trainer"]["lr"])
-    return {"hp": (float(h["b1"]), float(h["b2"]), float(h["eps"]),
-                   float(h["weight_decay"]), float(h["clip_norm"]), lr),
-            "devices": devices, "chunks": list(chunks),
-            "coef": float(c["router_aux_loss_coef"]),
-            "cap_multiple": int(c["bench"]["check"]["cap_multiple"])}
-
-
-def _reference_weights(make_params, devices):
-    """The seed's weights again, made straight into the reference's
-    placement on the cell's chips."""
-    import jax
-    from jax.sharding import Mesh
-    mesh = Mesh(np.asarray(devices), ("x",))
-    sp = mixtral_train.specs(mesh)
-    shard = {"embed": sp["embed"], "head": sp["head"],
-             "final_norm": sp["final_norm"]}
-
-    def build():
-        return reference_tree(make_params())
-
-    shapes = jax.eval_shape(build)
-    shard["layers"] = [sp["layer"]] * len(shapes["layers"])
-    return jax.jit(build, out_shardings=shard)()
 
 
 def _read_collectives(rec: TrainRun, out_dir: str, devices) -> None:

@@ -45,3 +45,20 @@ def window_steps(run) -> list:
 def pct(values, q: float):
     return float(np.percentile(np.asarray(values, np.float64), q)) \
         if len(values) else None
+
+
+#: the module of the paged decode wave (and its ``_loads`` twin)
+DECODE_WAVE = "jit_serve_decode_wave"
+
+
+def decode_scope_ms(run, scopes: tuple):
+    """Device time of the traced decode waves' ops under any of the program
+    scopes ``scopes`` (``bench/trace.py``'s ``scoped``), per wave, in ms;
+    None where the trace holds no wave or no op under them."""
+    if not is_serve(run) or run.trace is None:
+        return None
+    waves = run.trace.executions(DECODE_WAVE)
+    spent = run.trace.scope_s(DECODE_WAVE, scopes)
+    if not waves or spent <= 0:
+        return None
+    return 1e3 * spent / waves

@@ -2,7 +2,8 @@
 tokens excluded, routed experts only, the head once per prompt) over the
 summed host spans of the steps that ran a prefill chunk, over the chip's
 bf16 peak, in %.  Those spans also hold the step's decode wave, so the share
-can only read low."""
+can only read low.  Left out where the configuration's adapter counts no
+FLOPs."""
 from bench import flops, peaks
 from bench.metrics import _serve
 
@@ -15,8 +16,11 @@ def read(run):
         if chunk is None:
             continue
         start, stop, length = chunk
-        work += flops.prefill_flops(run.config, start, stop,
+        count = flops.prefill_flops(run.config, start, stop,
                                     logits=int(stop == length))
+        if count is None:            # the adapter counts no FLOPs
+            return None
+        work += count
         span += t1 - t0
     if span <= 0:
         return None

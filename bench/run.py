@@ -6,14 +6,19 @@
 Everything about a cell is data found by name: ``BENCHMARK.json`` names the
 cell's configuration and traffic mix; the configuration file
 (``bench/configs/<config>.json``) names the driver that runs it
-(``bench/drivers/<driver>.py``); the mix is ``bench/traffic/<mix>.json``;
+(``bench/drivers/<driver>.py``) and, by its ``model_type``, the adapter
+that holds what is its architecture's (``bench/arch/<model_type>.py``:
+the program's model, the plain reference, the counts of operations and
+bytes); the mix is ``bench/traffic/<mix>.json``;
 the limits of the correctness comparison are ``bench/limits/<cell>.json``;
 and each metric is computed by its own reader, ``bench/metrics/<metric>.py``.
 A reader that finds nothing to read returns None and the metric is left out.
 
 With ``--trace 0`` the result carries the cell's end-to-end metrics, with
 ``--trace 1`` its per-layer metrics, read partly from a profiler trace of
-the middle of the window.  The last line of standard output is one JSON
+the end of the window (``bench/trace.py``: device time by module and
+program scope, module executions, the program's spans) and from the
+program's own counters.  The last line of standard output is one JSON
 object (``correct``, ``attempted``, ``failed``, ``metrics``, ``device`` and,
 traced, ``breakdown``; ``checks`` comes last); the last lines of standard
 error repeat each compared number beside its limit.  Without a TPU, or with
@@ -143,7 +148,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     result = {"correct": run.correct, "attempted": run.attempted,
               "failed": run.failed}
     if trace:
-        prof = trace_mod.read_profile(out_dir)
+        prof = trace_mod.read_profile(
+            out_dir, host_ops=not require_chip,
+            scope_maps=getattr(run, "scope_maps", None))
         span = [(s, e) for n, s, e in prof.spans if n == "bench.window"]
         ops = [t for v in prof.device_ops.values() for _, a, b in v
                for t in (a, b)]

@@ -59,21 +59,21 @@ def as_program(variant: dict, refs: dict) -> dict:
 FIRST_STEP = ("grad_diff", "grad_norm_gap")
 
 
-def study(variants: tuple, limits: dict, dm, first_step: bool = False,
+def study(variants: tuple, limits: dict, first_step: bool = False,
           skew_seed=None):
     """An ``inspect`` hook for the training driver; it fills ``readings``
     with each variant's numbers, and with the leaves that set the
-    program's."""
+    program's, read from the reference module the driver hands it (the
+    cell's adapter's)."""
     import numpy as np
     from bench.drivers import train
-    from bench.models import mixtral_train
     readings = {}
 
-    def inspect(weights, batches, args, prog, ref):
+    def inspect(model, dm, weights, batches, args, prog, ref):
         readings["program"] = {"worst": worst(train.leaf_gaps(prog, ref,
                                                               limits))}
         if skew_seed is not None:
-            readings["skew"] = skew(weights, batches[0][0], dm, args,
+            readings["skew"] = skew(model, weights, batches[0][0], dm, args,
                                     skew_seed)
         if first_step:
             batches = batches[:1]
@@ -90,8 +90,8 @@ def study(variants: tuple, limits: dict, dm, first_step: bool = False,
                 kw["local_only"] = True
             else:
                 raise ValueError(f"unknown variant {v!r}")
-            out = mixtral_train.train_steps(weights, b, dm, **args, **kw,
-                                            keep_grad1=True)
+            out = model.train_steps(weights, b, dm, **args, **kw,
+                                    keep_grad1=True)
             mine = as_program(out, prog["refs"])
             if first_step:
                 gaps = train.leaf_gaps(mine, ref, limits)
@@ -110,13 +110,12 @@ def study(variants: tuple, limits: dict, dm, first_step: bool = False,
     return inspect, readings
 
 
-def skew(weights, tokens, dm, args, seed: int) -> dict:
+def skew(model, weights, tokens, dm, args, seed: int) -> dict:
     """The float32 router's top-k shares of ``tokens`` (one batch) over the
     experts and over the chips that hold them (experts split evenly), and
     the same for uniform ids of that shape drawn from ``seed``: max / mean
     of each, and the experts' shares."""
     import numpy as np
-    from bench.models import mixtral_train
     lp = weights["layers"][0]
     uniform = np.random.default_rng(seed).integers(
         0, dm.v, tokens.shape).astype(np.int32)
@@ -124,8 +123,8 @@ def skew(weights, tokens, dm, args, seed: int) -> dict:
     for name, rows in (("batch0", tokens), ("uniform", uniform)):
         count = np.zeros(dm.e)
         for t in rows:
-            x = mixtral_train._embed(weights["embed"], t, dm, None)
-            topk = np.asarray(mixtral_train._attn_route(lp, x, dm, None)[3])
+            x = model._embed(weights["embed"], t, dm, None)
+            topk = np.asarray(model._attn_route(lp, x, dm, None)[3])
             count += np.bincount(topk.reshape(-1), minlength=dm.e)
         share = count / count.sum()
         chips = share.reshape(args["devices"], -1).sum(1)
@@ -151,15 +150,12 @@ def main(argv=None) -> int:
     ap.add_argument("--skew", action="store_true")
     args = ap.parse_args(argv)
     run.add_paths()
-    from bench.models import mixtral
     cell = run.load_cell(args.workload)
     devices, driver = run.prepare(cell)
     variants = tuple(v for v in args.variants.split(",") if v)
     for seed in (int(s) for s in args.seeds.split(",")):
         t = time.perf_counter()
-        inspect, readings = study(variants, cell.limits,
-                                  mixtral.Dims.from_config(cell.config),
-                                  args.first_step,
+        inspect, readings = study(variants, cell.limits, args.first_step,
                                   seed if args.skew else None)
         rec = driver.run(cell, seed=seed, seconds=args.seconds, trace=False,
                          t_start=t, out_dir=run.OUT_DIR, devices=devices,

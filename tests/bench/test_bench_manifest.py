@@ -78,3 +78,35 @@ def test_metrics_have_readers_and_move_reported_metrics(manifest):
         assert m["moves"] in e2e and "\n" not in m["layer"]
         moved = set(e2e[m["moves"]].get("workloads", cells))
         assert set(m.get("workloads", cells)) <= moved
+
+
+#: what each driver takes from a configuration's adapter
+NEEDS = {"serve": ("program_config", "dims", "serve_reference",
+                   "reference_weights"),
+         "train": ("train_program_config", "dims", "train_reference",
+                   "reference_tree", "reference_leaves",
+                   "train_reference_weights", "reference_args")}
+
+
+def _configs(manifest):
+    """Every configuration of the manifest and of the held-out training
+    cell's entries."""
+    with open(os.path.join(ROOT, "tests", "bench", "train-cell.json")) as f:
+        held = json.load(f)
+    return manifest["configs"] + held["configs"]
+
+
+def test_every_configuration_has_an_architecture_adapter(manifest):
+    from bench import arch
+    for c in _configs(manifest):
+        with open(os.path.join(ROOT, c["file"])) as f:
+            conf = json.load(f)
+        path = os.path.join(ROOT, "bench", "arch",
+                            conf["model_type"] + ".py")
+        assert os.path.isfile(path), path
+        adapter = arch.adapter(conf)
+        assert os.path.samefile(adapter.__file__, path)
+        for name in NEEDS[conf["bench"]["driver"]]:
+            assert callable(getattr(adapter, name, None)) or \
+                hasattr(getattr(adapter, name, None), "__file__"), \
+                (conf["model_type"], name)

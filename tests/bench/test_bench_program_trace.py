@@ -1,21 +1,29 @@
-"""The reduction of the program's own spans and scopes
-(``bench/program_trace.py``): each number on a profile made by hand, the
+"""The reduction of the program's own spans, modules and scopes
+(``bench/trace.py``) and the numbers read from it (the harness's readers,
+``bench/program_trace.py``): each number on a profile made by hand, the
 benchmark's own numbers unchanged by it, and a whole serving run at toy
-widths traced on the CPU."""
+widths traced on the CPU, reduced as the earlier per-scope reduction did
+before the harness took the reduction over."""
 
 import json
+import math
 import os
+from collections import defaultdict
 
+import numpy as np
 import pytest
 
 from bench import program_trace as pt
 from bench import trace
 from bench.drivers.serve import ServeRun
+from bench.metrics import _serve
 
 MS = 1e6            # ns per ms
 DEV = "/device:TPU:0"
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
+DECODE_READERS = ("decode_moe_device_ms", "decode_attn_device_ms",
+                  "decode_cache_device_ms", "decode_expert_read_share")
 
 
 def _tiny_cell():
@@ -45,11 +53,20 @@ def _old_profile():
 
 
 def _program(base, ops=None, spans=(), runs=None):
+    """``base`` with the program's ops (default: ``base``'s, with no module
+    or scope), spans and module runs."""
     ops = ops if ops is not None else {
-        dev: [pt.Op(n, s, e, "", ()) for n, s, e in v]
+        dev: [trace.Op(n, s, e) for n, s, e in v]
         for dev, v in base.device_ops.items()}
-    return pt.ProgramProfile(base=base, spans=list(spans), ops=ops,
-                             runs=runs or {})
+    return trace.Profile(
+        device_ops={d: [(o.name, o.start, o.end) for o in v]
+                    for d, v in ops.items()},
+        spans=base.spans, ops=ops, runs=runs or {},
+        program_spans=list(spans))
+
+
+def _served(summary, **kw):
+    return ServeRun(trace=summary, **kw)
 
 
 def test_benchmark_numbers_unchanged_on_the_old_profile():
@@ -59,7 +76,7 @@ def test_benchmark_numbers_unchanged_on_the_old_profile():
     assert old.busy_s == pytest.approx((70 + 100) / 2 * 1e-9)
     assert old.idle_share == pytest.approx(0.15)
     assert old.idle_gaps == [["bench.wait", pytest.approx(15e-9)]]
-    new = pt.reduce(_program(base))
+    new = trace.summarize(_program(base))
     assert (new.window_s, new.busy_s, new.idle_share) == \
         (old.window_s, old.busy_s, old.idle_share)
     assert new.device_ops == old.device_ops
@@ -72,11 +89,11 @@ def test_gap_under_a_program_span_in_a_bench_step():
         spans=[("bench.window", 0, 100), ("bench.step", 0, 100)])
     spans = [("serve.step", 0, 100, {"step": 3}),
              ("serve.sample", 20, 50, {}), ("serve.admit", 55, 80, {})]
-    s = pt.reduce(_program(base, spans=spans))
+    s = trace.summarize(_program(base, spans=spans))
     # each gap goes to the innermost span at its middle
     assert dict(s.idle_gaps) == {"serve.sample": pytest.approx(30e-9),
                                  "serve.admit": pytest.approx(25e-9)}
-    # the benchmark's reduction still puts both gaps under bench.step
+    # without the program's spans both gaps fall under bench.step
     assert trace.summarize(base).idle_gaps == \
         [["bench.step", pytest.approx(55e-9)]]
 
@@ -85,10 +102,10 @@ def test_same_named_ops_of_two_modules_do_not_merge():
     base = trace.Profile(device_ops={DEV: [("fusion.10", 0, 30),
                                            ("fusion.10", 40, 50)]},
                          spans=[("bench.window", 0, 100)])
-    ops = {DEV: [pt.Op("fusion.10", 0, 30, "jit_serve_decode_wave",
-                       ("moe", "experts")),
-                 pt.Op("fusion.10", 40, 50, "jit_serve_install", ())]}
-    s = pt.reduce(_program(base, ops=ops))
+    ops = {DEV: [trace.Op("fusion.10", 0, 30, "jit_serve_decode_wave",
+                          ("moe", "experts")),
+                 trace.Op("fusion.10", 40, 50, "jit_serve_install", ())]}
+    s = trace.summarize(_program(base, ops=ops))
     assert s.device_ops == [
         ["jit_serve_decode_wave/experts/fusion.10", pytest.approx(30e-9)],
         ["jit_serve_install/fusion.10", pytest.approx(10e-9)]]
@@ -106,7 +123,7 @@ def test_step_self_time_subtracts_overlapping_fetches_once():
              ("serve.fetch", 21 * MS, 22 * MS, {}),
              ("serve.step", 30 * MS, 33 * MS, {}),
              ("serve.fetch", 40 * MS, 45 * MS, {})]   # outside every step
-    s = pt.reduce(_program(base, spans=spans))
+    s = trace.summarize(_program(base, spans=spans))
     # self times 10 - 6, 4 - 1 and 3: the median is 3 ms
     assert pt.step_host_ms(s) == pytest.approx(3.0)
 
@@ -117,36 +134,43 @@ def test_scope_union_over_waves():
     wave = "jit_serve_decode_wave"
     ops = {DEV: [
         # wave 1, 0-10 ms: a while op holding its body's ops
-        pt.Op("while.14", 0, 9 * MS, wave, ()),
-        pt.Op("fusion.1", 0, 2 * MS, wave, ("attention",)),
-        pt.Op("fusion.2", 2 * MS, 7 * MS, wave, ("moe", "experts")),
-        pt.Op("fusion.3", 6 * MS, 8 * MS, wave, ("moe", "combine")),
-        pt.Op("fusion.4", 9 * MS, 10 * MS, wave, ("paged_scatter",)),
+        trace.Op("while.14", 0, 9 * MS, wave, ()),
+        trace.Op("fusion.1", 0, 2 * MS, wave, ("attention",)),
+        trace.Op("fusion.2", 2 * MS, 7 * MS, wave, ("moe", "experts")),
+        trace.Op("fusion.3", 6 * MS, 8 * MS, wave, ("moe", "combine")),
+        trace.Op("fusion.4", 9 * MS, 10 * MS, wave, ("paged_scatter",)),
         # wave 2, 20-32 ms
-        pt.Op("fusion.5", 20 * MS, 21 * MS, wave, ("paged_gather",)),
-        pt.Op("fusion.1", 21 * MS, 23 * MS, wave, ("attention",)),
-        pt.Op("fusion.2", 23 * MS, 29 * MS, wave, ("moe", "experts")),
-        pt.Op("fusion.4", 29 * MS, 32 * MS, wave, ("paged_scatter",)),
+        trace.Op("fusion.5", 20 * MS, 21 * MS, wave, ("paged_gather",)),
+        trace.Op("fusion.1", 21 * MS, 23 * MS, wave, ("attention",)),
+        trace.Op("fusion.2", 23 * MS, 29 * MS, wave, ("moe", "experts")),
+        trace.Op("fusion.4", 29 * MS, 32 * MS, wave, ("paged_scatter",)),
         # a prefill chunk's moe is not a decode wave's
-        pt.Op("fusion.9", 40 * MS, 60 * MS, "jit_serve_prefill_chunk",
-              ("moe",))]}
+        trace.Op("fusion.9", 40 * MS, 60 * MS, "jit_serve_prefill_chunk",
+                 ("moe",))]}
     runs = {DEV: [(wave, 0, 10 * MS), (wave, 20 * MS, 32 * MS),
                   ("jit_serve_prefill_chunk", 40 * MS, 60 * MS)]}
-    s = pt.reduce(_program(base, ops=ops, runs=runs))
+    s = trace.summarize(_program(base, ops=ops, runs=runs))
+    assert s.executions(wave) == 2
+    assert s.scoped[(wave, "moe")] == pytest.approx((6 + 6) * 1e-3)
     assert pt.decode_wave_device_ms(s) == pytest.approx(11.0)
-    assert pt.decode_scope_ms(s, ("moe",)) == pytest.approx((6 + 6) / 2)
-    assert pt.decode_scope_ms(s, ("attention",)) == pytest.approx(2.0)
-    assert pt.decode_scope_ms(s, ("paged_gather", "paged_scatter")) == \
-        pytest.approx((1 + 1 + 3) / 2)
+    run = _served(s)
+    assert _serve.decode_scope_ms(run, ("moe",)) == pytest.approx(
+        (6 + 6) / 2)
+    assert _serve.decode_scope_ms(run, ("attention",)) == pytest.approx(2.0)
+    assert _serve.decode_scope_ms(run, ("paged_gather", "paged_scatter")) \
+        == pytest.approx((1 + 1 + 3) / 2)
+    # a scope no op carries gives no number, never 0
+    assert _serve.decode_scope_ms(run, ("lm_head",)) is None
 
 
 def test_prefill_flops_come_from_the_span_arguments(monkeypatch):
     from bench import flops, peaks
     monkeypatch.setitem(peaks.PEAKS, "toy", peaks.Peaks(1e12, 1e11, "toy"))
-    cfg = {"hidden_size": 64, "intermediate_size": 128,
-           "num_hidden_layers": 2, "num_attention_heads": 4,
-           "num_key_value_heads": 2, "num_local_experts": 4,
-           "num_experts_per_tok": 2, "vocab_size": 256}
+    cfg = {"model_type": "mixtral", "hidden_size": 64,
+           "intermediate_size": 128, "num_hidden_layers": 2,
+           "num_attention_heads": 4, "num_key_value_heads": 2,
+           "num_local_experts": 4, "num_experts_per_tok": 2,
+           "vocab_size": 256}
     base = trace.Profile(device_ops={DEV: [("x", 0, 1)]},
                          spans=[("bench.window", 0, 100 * MS)])
     spans = [("serve.prefill_chunk", 1 * MS, 2 * MS,
@@ -159,7 +183,7 @@ def test_prefill_flops_come_from_the_span_arguments(monkeypatch):
     runs = {DEV: [("jit_serve_prefill_chunk", 1 * MS, 2 * MS),
                   ("jit_serve_extend_chunk", 5 * MS, 8 * MS),
                   ("jit_serve_decode_wave", 9 * MS, 19 * MS)]}
-    s = pt.reduce(_program(base, spans=spans, runs=runs))
+    s = trace.summarize(_program(base, spans=spans, runs=runs))
     work = (flops.prefill_flops(cfg, 0, 32)
             + flops.prefill_flops(cfg, 32, 64, logits=1))
     assert pt.prefill_device_mfu(s, cfg, "toy") == \
@@ -167,33 +191,37 @@ def test_prefill_flops_come_from_the_span_arguments(monkeypatch):
 
 
 def test_queue_wait_counts_an_unadmitted_request_to_the_drain():
-    run = ServeRun(window=(10.0, 20.0), drain_end=25.0,
-                   in_window={1, 2, 3, 4},
-                   submitted={0: 9.0, 1: 10.0, 2: 11.0, 3: 12.0, 4: 13.0})
     admitted = {0: 9.5, 1: 10.5, 2: 13.0, 3: 12.0}   # 4 never admitted
+    run = ServeRun(window=(10.0, 20.0), drain_end=25.0,
+                   in_window={1, 2, 3, 4}, admitted=admitted,
+                   submitted={0: 9.0, 1: 10.0, 2: 11.0, 3: 12.0, 4: 13.0})
     waits_ms = [500.0, 2000.0, 0.0, 12000.0]
-    import numpy as np
-    assert pt.queue_wait_p90_ms(run, admitted) == \
+    assert pt.queue_wait_p90_ms(run) == \
         pytest.approx(float(np.percentile(waits_ms, 90)))
     # a program that stamps no admission gives no number
-    assert pt.queue_wait_p90_ms(run, {}) is None
+    run.admitted = {}
+    assert pt.queue_wait_p90_ms(run) is None
 
 
 def test_an_op_takes_the_module_running_around_it():
     runs = [("jit_a", 0, 10), ("jit_b", 10, 20), ("jit_c", 30, 40)]
     starts = [r[1] for r in runs]
-    assert [pt._module_at(runs, starts, t)
+    assert [trace._module_at(runs, starts, t)
             for t in (-1, 0, 9, 10, 25, 30, 39, 40)] == \
         ["", "jit_a", "jit_a", "jit_b", "", "jit_c", "jit_c", ""]
 
 
 def test_scope_path_reads_through_transforms():
-    assert pt.scope_path("jit(serve_decode_wave)/vmap()/while/body/"
-                         "closed_call/moe/experts/jit(silu)/neg") == \
+    assert trace.scope_path("jit(serve_decode_wave)/vmap()/while/body/"
+                            "closed_call/moe/experts/jit(silu)/neg") == \
         ("moe", "experts")
-    assert pt.scope_path("jit(f)/vmap(attention)/dot_general") == \
+    assert trace.scope_path("jit(f)/vmap(attention)/dot_general") == \
         ("attention",)
-    assert pt.scope_path("jit(f)/add") == ()
+    assert trace.scope_path("jit(f)/add") == ()
+    # an adapter's own scope names are read where it names them
+    assert trace.scope_path("jit(f)/mla/dot_general") == ()
+    assert trace.scope_path("jit(f)/mla/dot_general",
+                            trace.SCOPES + ("mla",)) == ("mla",)
     text = ('%fused_computation.7 (param_0: f32[4]) -> f32[4] {\n'
             '  %param_0 = f32[4]{0} parameter(0)\n'
             '  ROOT %neg.2 = f32[4]{0} negate(%param_0), '
@@ -206,65 +234,125 @@ def test_scope_path_reads_through_transforms():
             'calls=%fused_computation.7\n'
             '  ROOT %t = (f32[4]{0}) tuple(%fusion.3)\n'
             '}\n')
-    assert pt.hlo_scopes(text) == {"fusion.3": ("lm_head",),
-                                   "neg.2": ("moe", "experts"),
-                                   "fusion.7": ("moe", "experts")}
+    assert trace.hlo_scopes(text) == {"fusion.3": ("lm_head",),
+                                      "neg.2": ("moe", "experts"),
+                                      "fusion.7": ("moe", "experts")}
 
 
-def test_a_traced_serving_run_on_the_cpu(tmp_path, monkeypatch):
-    """The tiny cell through the serving driver with the profiler on: every
-    number reads, and the decode wave's scopes fit inside it."""
-    import jax
-    from bench import peaks, run as run_mod
-    from bench.drivers import serve
+# -- a traced serving run on the CPU ------------------------------------------
+
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    """The tiny cell through the serving driver with the profiler on; the
+    trace stays on disk."""
+    from bench import run as run_mod
+    out = str(tmp_path_factory.mktemp("program_trace"))
     cell = _tiny_cell()
-    kept = {}
-    real = serve.Server.serve
-
-    def keep(self, gen, rec, *a, **kw):
-        reqs = real(self, gen, rec, *a, **kw)
-        kept["admitted"] = {rid: self.t0 + r.t_admit
-                            for rid, r in reqs.items()
-                            if r.t_admit is not None}
-        kept["maps"] = pt.serving_scope_maps(self)
-        return reqs
-
-    monkeypatch.setattr(serve.Server, "serve", keep)
     run_mod.add_paths()
     devices, driver = run_mod.prepare(cell, require_chip=False)
     rec = driver.run(cell, seed=11, seconds=2.0, trace=True, t_start=0.0,
-                     out_dir=str(tmp_path), devices=devices)
+                     out_dir=out, devices=devices)
+    return cell, rec, out
+
+
+def _read(traced_run, top=10):
+    _, rec, out = traced_run
+    prof = trace.read_profile(out, host_ops=True, scope_maps=rec.scope_maps)
+    return prof, trace.summarize(prof, top)
+
+
+def test_a_traced_serving_run_on_the_cpu(traced, monkeypatch):
+    """Every number reads, and the decode wave's scopes fit inside it."""
+    import jax
+    from bench import peaks
+    _, rec, out = traced
     assert rec.correct, rec.checks
-    pp = pt.read_program(str(tmp_path), host_ops=True,
-                         scope_maps=kept["maps"])
-    names = {n for n, _, _, _ in pp.spans}
+    assert set(rec.scope_maps) == {"jit_serve_decode_wave",
+                                   "jit_serve_prefill_chunk",
+                                   "jit_serve_extend_chunk"}
+    prof, s = _read(traced)
+    names = {n for n, _, _, _ in prof.program_spans}
     assert {"serve.step", "serve.admit", "serve.prefill_chunk",
             "serve.fetch", "serve.sample", "serve.decode_dispatch"} <= names
-    mods = {o.module for ops in pp.ops.values() for o in ops}
+    mods = {o.module for ops in prof.ops.values() for o in ops}
     assert {"jit_serve_decode_wave", "jit_serve_prefill_chunk",
             "jit_serve_install"} <= mods
     assert "jit_fn" not in mods
-    s = pt.reduce(pp)
-    old = trace.summarize(pp.base)
-    # one read of the trace gives the benchmark's own profile too
-    assert old == trace.summarize(trace.read_profile(str(tmp_path), True))
-    assert (s.window_s, s.busy_s) == (old.window_s, old.busy_s)
+    rec.trace = s
     wave = pt.decode_wave_device_ms(s)
-    parts = [pt.decode_scope_ms(s, sc) for sc in
+    parts = [_serve.decode_scope_ms(rec, sc) for sc in
              (("moe",), ("attention",), ("paged_gather", "paged_scatter"))]
     assert wave > 0 and all(p > 0 for p in parts)
     assert sum(parts) <= wave * 1.001
     monkeypatch.setitem(peaks.PEAKS, jax.devices()[0].device_kind,
                         peaks.Peaks(1e12, 1e11, "toy"))
-    got = pt.metrics(s, rec, kept["admitted"])
-    assert set(got) == {"decode_wave_device_ms", "decode_moe_device_ms",
-                        "decode_attn_device_ms", "decode_cache_device_ms",
-                        "prefill_device_mfu", "step_host_ms",
-                        "queue_wait_p90_ms"}
+    got = pt.metrics(rec)
+    assert set(got) == {"decode_wave_device_ms", "prefill_device_mfu",
+                        "step_host_ms", "queue_wait_p90_ms"}
     assert 0 < got["step_host_ms"] and got["prefill_device_mfu"] > 0
     assert 0 <= got["queue_wait_p90_ms"]
-    # idle time inside the benchmark's step spans is now named by the
-    # program's spans
+    # idle time inside the benchmark's step spans is named by the program's
+    # spans
     under_step = dict(s.idle_gaps).get("bench.step", 0.0)
     program = sum(v for k, v in s.idle_gaps if k.startswith("serve."))
     assert program > under_step
+
+
+def _old_scoped(trace_dir, scope_maps):
+    """Seconds per (module, scope), mean over devices, as the earlier
+    per-scope reduction read them before ``bench/trace.py`` took it over: the
+    host's op events with their ``hlo_module`` stat, each op's scopes from
+    the maps, the union of each (module, scope)'s intervals per device
+    clipped to ``bench.window``."""
+    import glob
+    from jax.profiler import ProfileData
+    path = max(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                         recursive=True), key=os.path.getmtime)
+    win, ops = [], []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                s, e = ev.start_ns, ev.start_ns + ev.duration_ns
+                if ev.name == "bench.window":
+                    win.append((s, e))
+                elif ev.name.startswith(("bench.", "serve.")):
+                    continue
+                else:
+                    st = dict(ev.stats)
+                    if "hlo_op" in st:
+                        ops.append((ev.name.split(" = ", 1)[0].lstrip("%"),
+                                    s, e, st.get("hlo_module", "")))
+    lo, hi = ((min(s for s, _ in win), max(e for _, e in win)) if win
+              else (-math.inf, math.inf))
+    by_scope = defaultdict(list)
+    for name, s, e, module in ops:
+        for sc in set(scope_maps.get(module, {}).get(name, ())):
+            by_scope[(module, sc)].append((s, e))
+    return {k: trace.total(trace.clip(trace.merge(iv), lo, hi)) * 1e-9
+            for k, iv in by_scope.items()}
+
+
+def test_harness_scopes_equal_the_old_reduction(traced):
+    _, rec, out = traced
+    _, s = _read(traced)
+    old = _old_scoped(out, rec.scope_maps)
+    assert {k for k, v in old.items() if v > 0} == \
+        {k for k, v in s.scoped.items() if v > 0}
+    assert any(m == "jit_serve_decode_wave" for m, _ in s.scoped)
+    for k, v in old.items():
+        assert s.scoped.get(k, 0.0) == pytest.approx(v, rel=1e-12, abs=0)
+
+
+def test_the_four_decode_readers_read_a_recorded_run(traced):
+    from bench import run as run_mod
+    cell, rec, _ = traced
+    _, rec.trace = _read(traced)
+    got = {n: run_mod.read_metric(n, rec) for n in DECODE_READERS}
+    assert all(v is not None and v > 0 for v in got.values()), got
+    assert got["decode_expert_read_share"] <= 100
+    assert set(rec.counters) == {"open", "close"}
+    # the cell reports each of them
+    names = [m["name"] for m in run_mod.cell_metrics(cell, True)]
+    assert set(DECODE_READERS) <= set(names)

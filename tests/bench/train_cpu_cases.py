@@ -122,14 +122,15 @@ def peer_expert_grads_zeroed():
 
 def token_drops():
     """Tokens dropped: capacity factor 1.0 in place of dropless routing."""
-    from bench.drivers import train
-    real = train.program_config
+    from bench import arch
+    adapter = arch.adapter(cell().config)
+    real = adapter.train_program_config
 
     def config(c):
         cfg = real(c)
         return dataclasses.replace(cfg, moe=dataclasses.replace(
             cfg.moe, capacity_mode="capacity", capacity_factor=1.0))
-    return patched(train, "program_config", config)
+    return patched(adapter, "train_program_config", config)
 
 
 def neighbour_recompute():
@@ -165,10 +166,8 @@ def case_program():
     """The program, and the control (int8 W8A8) in its place."""
     from bench import run, train_control
     from bench.drivers import train
-    from bench.models import mixtral
     c = cell()
-    inspect, readings = train_control.study(
-        ("int8",), c.limits, mixtral.Dims.from_config(c.config))
+    inspect, readings = train_control.study(("int8",), c.limits)
     real = train.run
 
     def with_control(*a, **kw):
